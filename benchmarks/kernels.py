@@ -7,6 +7,8 @@ the numbers the §Roofline analysis uses for the kernels' hot paths.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from benchmarks.common import print_table, save_result
@@ -15,6 +17,7 @@ from benchmarks.common import print_table, save_result
 def bench_kernels(quick=False):
     import jax.numpy as jnp
 
+    from repro.kernels import for_platform
     from repro.kernels.decode_attention.kernel import decode_attention_pallas
     from repro.kernels.decode_attention.ref import decode_attention_ref
     from repro.kernels.flash_attention.ops import flash_attention
@@ -30,7 +33,7 @@ def bench_kernels(quick=False):
     q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
-    out_k = flash_attention(q, k, v, interpret=True)
+    out_k = flash_attention(q, k, v)
     out_r = model_flash(q, k, v, causal=True, q_chunk=128, kv_chunk=128)
     flops = 4 * (s * s / 2) * hq * d * b  # causal QK^T + PV
     rows.append({
@@ -45,7 +48,8 @@ def bench_kernels(quick=False):
     kc = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     vc = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     lengths = jnp.full((b,), s, jnp.int32)
-    o_k = decode_attention_pallas(q2, kc, vc, lengths, block_k=256)
+    o_k = for_platform(
+        functools.partial(decode_attention_pallas, block_k=256), q2, kc, vc, lengths)
     o_r = decode_attention_ref(q2, kc, vc, lengths)
     rows.append({
         "kernel": "decode_attention", "max_err": float(jnp.abs(o_k - o_r).max()),

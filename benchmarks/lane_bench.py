@@ -51,7 +51,7 @@ import numpy as np
 from repro.core import Worker, make_policy
 from repro.data.applications import APP_SPECS, build_benchmark_suite, make_requests
 from repro.serving import EdgeServer, LMExecutor, SimulatedBackend
-from repro.serving.runtime import LANE_NAMES
+from repro.serving.runtime import LANE_NAMES, require_cpu_platform
 
 ROOT = Path(__file__).resolve().parents[1]
 WINDOW_S = 0.1
@@ -152,6 +152,9 @@ def main():
         default=str(ROOT / "results" / "benchmarks" / "BENCH_lanes.json"),
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     n_req = args.requests or (64 if args.quick else 1024)
     n_win = args.windows or (2 if args.quick else 16)
@@ -164,6 +167,8 @@ def main():
     for m in modes:
         if m not in ("sync", "overlap"):
             raise SystemExit(f"unknown mode {m!r}; expected sync or overlap")
+    if "process" in lanes:
+        require_cpu_platform("lane_bench --lane process")
 
     # Lane threads wake from many short modelled sleeps; with the default
     # 5 ms GIL switch interval each wake-up stalls behind whatever the

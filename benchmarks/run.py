@@ -18,6 +18,9 @@ def main():
     ap.add_argument("--quick", action="store_true", help="fewer seeds/sizes")
     ap.add_argument("--only", type=str, default="", help="comma list, e.g. fig5,kernels")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import paper_figs as pf
     from benchmarks.kernels import bench_kernels

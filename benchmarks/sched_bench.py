@@ -574,7 +574,6 @@ def shard_child(num_devices: int, n: int, chunk: int) -> dict:
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.pipeline import WindowPipeline, _chunk_member_mean, _penalty_jnp
     from repro.core.shard import ShardedWindowPipeline, pad_rows
@@ -600,7 +599,7 @@ def shard_child(num_devices: int, n: int, chunk: int) -> dict:
 
     def time_tile(rows: int) -> float:
         rng = np.random.default_rng(0)
-        with enable_x64():
+        with jax.enable_x64(True):
             args = (
                 jnp.float64(0.01),
                 jnp.asarray(rng.random((rows, B, M))),
@@ -664,6 +663,9 @@ def run_shard(device_counts, n, chunk):
     import os
     import subprocess
 
+    from repro.serving.runtime import require_cpu_platform
+
+    require_cpu_platform("sched_bench --shard (forced host devices)")
     rows = []
     for d in device_counts:
         env = dict(os.environ)
@@ -810,6 +812,9 @@ def main():
         default=str(ROOT / "results" / "benchmarks" / "BENCH_sched.json"),
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.shard_child:
         row = shard_child(args.shard_child, args.shard_n, args.shard_chunk)

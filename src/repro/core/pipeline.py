@@ -40,7 +40,7 @@ included, with the paper's conservative single-slot model folded in via
 ``residency.single_slot_encoding`` (no host fallback for carried
 capacity states).
 
-Programs run under ``jax.experimental.enable_x64`` so decisions match
+Programs run under ``jax.enable_x64(True)`` so decisions match
 the float64 numpy fast path and the scalar reference (the parity suite
 in tests/test_pipeline.py asserts identical schedules for all five
 policies, single- and multi-worker, with and without capacity limits).
@@ -1322,9 +1322,9 @@ class WindowPipeline:
         )
 
     def _enable_x64(self):
-        from jax.experimental import enable_x64
+        import jax
 
-        return enable_x64()
+        return jax.enable_x64(True)
 
     def _schedule_per_request_jax(self, policy, requests, now, state, arrays):
         if policy.selection not in ("locally_optimal", "max_accuracy"):

@@ -23,9 +23,12 @@ follows what float arithmetic allows:
     only the downstream tiles.
   * **Argmaxes all-reduce exactly.**  The global Eq. 2/13 argmax over a
     sharded axis is comparisons only: each shard reduces its rows
-    (first-max, same tie-preference column order), then ``pmax`` on the
-    value and ``pmin`` on the tie-break rank pick the same winner the
-    single-device first-max would — no float arithmetic crosses shards.
+    (first-max, same tie-preference column order), then a global max on
+    the value and a global min on the tie-break rank pick the same
+    winner the single-device first-max would — no float arithmetic
+    crosses shards.  The global max/min gather every shard's value and
+    reduce locally (``_all_max``/``_all_min``): a TPU all-reduces 64-bit
+    values by sum only.
   * **The sequential carry reconciles replicated.**  Queue-tail time and
     LRU residency are inherently sequential; the sharded selector runs
     the speculate/validate rounds of ``pipeline._spec_select`` with the
@@ -40,7 +43,7 @@ follows what float arithmetic allows:
 
 Single-worker policies shard the request axis; the multi-worker Eq. 15
 placement shards the WORKER axis of its (worker, batch, model) tiles and
-resolves each step's placement with the pmax/pmin all-reduce argmax
+resolves each step's placement with the max/min all-reduce argmax
 under the shared tie-break permutation (rank = position in
 ``fastpath.placement_pref`` — globally unique, so the reduce is exact).
 Rows/workers padded up to a multiple of the shard count are encoded
@@ -78,7 +81,7 @@ __all__ = [
 ]
 
 # Tie-break rank sentinel: larger than any real preference position, small
-# enough that int64 pmin arithmetic never overflows.
+# enough that int64 min arithmetic never overflows.
 _RANK_INF = np.int64(2**62)
 # One (S,)-mesh per shard count, shared across pipelines (device order is
 # stable within a process, so equal counts mean equal meshes).
@@ -154,6 +157,18 @@ def _place(mesh, tabs: dict, specs: dict):
     return {k: jax.device_put(v, ns[k]) for k, v in tabs.items()}
 
 
+def _all_max(jnp, jax, x):
+    """Max over the "shard" axis, exact in any order: every shard's value
+    is gathered and reduced locally (a TPU all-reduces 64-bit values by
+    sum only)."""
+    return jnp.max(jax.lax.all_gather(x, "shard"), axis=0)
+
+
+def _all_min(jnp, jax, x):
+    """Min over the "shard" axis (see ``_all_max``)."""
+    return jnp.min(jax.lax.all_gather(x, "shard"), axis=0)
+
+
 # --------------------------------------------------------------------------
 # Sharded single-carry selection (per-request + grouped policies)
 # --------------------------------------------------------------------------
@@ -169,7 +184,7 @@ def _sharded_select_program(kind, res_mode, num_shards, fixed):
     computed per shard on that shard's row block, and the scalar
     reconstruction chain runs REPLICATED on every shard from the exact
     per-position picks (``all_gather`` — bit-exact copies).  The first
-    conflict is an all-reduce ``pmin`` over global row indices.
+    conflict is a global min (``_all_min``) over global row indices.
     ``k_eff`` caps the accepted prefix per round: passing the policy's
     chunk reproduces the single-device chunked rounds (same conflicts,
     same stats); passing the window length speculates everything left
@@ -184,7 +199,6 @@ def _sharded_select_program(kind, res_mode, num_shards, fixed):
         return prog
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shard_mesh(num_shards)
@@ -288,7 +302,7 @@ def _sharded_select_program(kind, res_mode, num_shards, fixed):
             # accept through it (inclusive), capped at k_eff.
             mism = (j_true != j_spec) & active
             loc_first = jnp.min(jnp.where(mism, rows, _RANK_INF))
-            first = jax.lax.pmin(loc_first, "shard")
+            first = _all_min(jnp, jax, loc_first)
             any_m = first < _RANK_INF
             a = jnp.where(any_m, first + 1 - p, jnp.minimum(k_eff, n_total - p))
 
@@ -327,12 +341,12 @@ def _sharded_select_program(kind, res_mode, num_shards, fixed):
     if fixed:
         tab_names += ["sel"]
     tab_specs = {k: P("shard") for k in tab_names}
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), tab_specs),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     prog = jax.jit(mapped)
     _PROGRAMS[key] = prog
@@ -346,23 +360,20 @@ def _sharded_select_program(kind, res_mode, num_shards, fixed):
 
 def _pick_allreduce(jnp, jax, u_flat, rank_flat):
     """Exact global first-max under the preference permutation: local
-    first-max (max utility, min rank among local ties), then ``pmax`` on
-    the value and ``pmin`` on the rank — comparisons only, so the winner
-    is bit-for-bit the single-device argmax over the permuted tile.
-    Works elementwise over any leading axes."""
+    first-max (max utility, min rank among local ties), then a global max
+    on the value and a global min on the rank — comparisons only, so the
+    winner is bit-for-bit the single-device argmax over the permuted
+    tile.  Works elementwise over any leading axes."""
     ub = jnp.max(u_flat, axis=-1)
     rb = jnp.min(jnp.where(u_flat == ub[..., None], rank_flat, _RANK_INF), axis=-1)
-    u_star = jax.lax.pmax(ub, "shard")
-    r_star = jax.lax.pmin(
-        jnp.where(ub == u_star, rb, _RANK_INF), "shard"
-    )
-    return r_star
+    u_star = _all_max(jnp, jax, ub)
+    return _all_min(jnp, jax, jnp.where(ub == u_star, rb, _RANK_INF))
 
 
 def _owner_bcast(jnp, jax, mine, val):
-    """Broadcast the picking shard's float value (exact copy via pmax
-    against -inf fillers)."""
-    return jax.lax.pmax(jnp.where(mine, val, -jnp.inf), "shard")
+    """Broadcast the picking shard's float value (exact copy via a global
+    max against -inf fillers)."""
+    return _all_max(jnp, jax, jnp.where(mine, val, -jnp.inf))
 
 
 def _sharded_mw_program(res_mode, num_shards):
@@ -370,7 +381,7 @@ def _sharded_mw_program(res_mode, num_shards):
     groups whose (worker, batch, model) utility tile is split along the
     WORKER axis — each shard scores its worker block (elementwise rows +
     the scalar-order member mean, bit-identical to the full tile's rows)
-    — with the placement argmax resolved by the pmax/pmin all-reduce
+    — with the placement argmax resolved by the max/min all-reduce
     under the tie-break rank (the inverse ``placement_pref``
     permutation).  The pool carry (busy-until times + residency) is
     replicated: every shard applies the same winning update.  Workers
@@ -382,7 +393,6 @@ def _sharded_mw_program(res_mode, num_shards):
         return prog
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shard_mesh(num_shards)
@@ -441,7 +451,7 @@ def _sharded_mw_program(res_mode, num_shards):
         "w_valid": P("shard"), "lat_tab": P(None, "shard"),
         "sswap": P(None, "shard"), "rank_tab": P(None, "shard"),
     }
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -450,7 +460,7 @@ def _sharded_mw_program(res_mode, num_shards):
             P(), worker_axis["rank_tab"],
         ),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     prog = jax.jit(mapped)
     _PROGRAMS[key] = prog
@@ -461,7 +471,7 @@ def _sharded_mw_spec_program(res_mode, num_shards, chunk):
     """Chunked sharded Eq. 15: ``pipeline._spec_select_mw``'s speculate-
     K/validate/fallback rounds with the (K, worker, batch, model) tiles
     split along the worker axis.  Per-round picks use the vectorized
-    pmax/pmin all-reduce argmax; the pool-carry reconstruction chain and
+    max/min all-reduce argmax; the pool-carry reconstruction chain and
     the accept/commit step run replicated (same ops on every shard from
     owner-broadcast picked scalars) — identical rounds, conflicts and
     decisions to the single-device chunked driver."""
@@ -471,7 +481,6 @@ def _sharded_mw_spec_program(res_mode, num_shards, chunk):
         return prog
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shard_mesh(num_shards)
@@ -625,12 +634,12 @@ def _sharded_mw_spec_program(res_mode, num_shards, chunk):
         "gid": P(), "valid": P(), "pen": P(), "pref": P(),
         "rank": P(None, "shard"),
     }
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P("shard"), tab_specs),
         out_specs=(P(), P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     prog = jax.jit(mapped)
     _PROGRAMS[key] = prog
@@ -922,7 +931,7 @@ class ShardedWindowPipeline(WindowPipeline):
         lat_tab = np.pad(setup["lat_tab"], [(0, 0), (0, wp), (0, 0)])
         sswap = np.pad(tab["sswap"], [(0, 0), (0, wp), (0, 0)])
         # rank[a, w, m] = position of (w, m) in the app's tie-break
-        # preference permutation (the all-reduce pmin key); pref_rep maps
+        # preference permutation (the all-reduce min key); pref_rep maps
         # the winning rank back to the base (w * m_max + m) pick.
         pref = tab["pref"]  # (A, n_w * m_max)
         n_apps = pref.shape[0]

@@ -9,8 +9,8 @@ Implementations:
   * ``KNNSneakPeek`` — the paper's primary mechanism: k nearest neighbors
     in the training set vote (e.g. k=5, two "no fall" + three "fall" ->
     y = <2, 3>).  The distance/top-k computation runs through the Pallas
-    TPU kernel (``repro.kernels.knn``) when available, with a numpy
-    fallback (the paper uses Faiss on CPU).
+    TPU kernel (``repro.kernels.knn``), or through an exact numpy search
+    when ``backend="numpy"`` is asked for (the paper uses Faiss on CPU).
   * ``DecisionRuleSneakPeek`` — the "low-information" one-hot alternative
     discussed in §IV-B.
   * ``ConfusionSneakPeek`` — the synthetic model of Fig. 8: given a target
@@ -87,7 +87,13 @@ class SneakPeekModel:
 
 
 class KNNSneakPeek(SneakPeekModel):
-    """k-NN vote evidence against the (sub-sampled) training set."""
+    """k-NN vote evidence against the (sub-sampled) training set.
+
+    ``backend`` "auto" and "jax" run the Pallas k-NN kernel (a kernel
+    error propagates); "numpy" runs the exact search on the host.
+    """
+
+    BACKENDS = ("auto", "jax", "numpy")
 
     def __init__(
         self,
@@ -106,6 +112,8 @@ class KNNSneakPeek(SneakPeekModel):
             raise ValueError("train_x must be (N, D), train_y (N,)")
         if k < 1:
             raise ValueError("k must be >= 1")
+        if backend not in self.BACKENDS:
+            raise ValueError(f"unknown backend {backend!r}; expected one of {self.BACKENDS}")
         self.num_classes = int(num_classes)
         self.k = int(k)
         self.name = name
@@ -123,19 +131,15 @@ class KNNSneakPeek(SneakPeekModel):
     def _votes(self, queries: np.ndarray) -> np.ndarray:
         """(B, num_classes) vote counts for a batch of queries."""
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-        if self.backend in ("auto", "jax"):
-            try:
-                from repro.kernels.knn import ops as knn_ops
+        if self.backend != "numpy":
+            from repro.kernels.knn import ops as knn_ops
 
-                return np.asarray(
-                    knn_ops.knn_class_votes(
-                        queries, self.train_x, self.train_y, self.k, self.num_classes
-                    )
+            return np.asarray(
+                knn_ops.knn_class_votes(
+                    queries, self.train_x, self.train_y, self.k, self.num_classes
                 )
-            except Exception:
-                if self.backend == "jax":
-                    raise
-        # numpy fallback (Faiss-equivalent exact search)
+            )
+        # numpy exact search (what the paper's Faiss-on-CPU computes)
         d2 = (
             (queries**2).sum(1)[:, None]
             - 2.0 * queries @ self.train_x.T

@@ -194,7 +194,4 @@ def shard_act(x, name: str):
             used.update(names)
             break
         spec.append(chosen)
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PartitionSpec(*spec)))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PartitionSpec(*spec)))

@@ -70,7 +70,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention_pallas(
     q, k_cache, v_cache, lengths, *, window: int = 0,
-    block_k: int = 256, scale: float | None = None, interpret: bool = True,
+    block_k: int = 256, scale: float | None = None, interpret: bool,
 ):
     """q: (B, Hkv, G, D);  k/v_cache: (B, Hkv, S, D);  lengths: (B,) int32.
 

@@ -5,15 +5,16 @@ import functools
 
 import jax
 
+from repro.kernels import for_platform
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 __all__ = ["decode_attention"]
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("window", "use_kernel"))
 def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
-                     interpret: bool = True, use_kernel: bool = True):
+                     use_kernel: bool = True):
     """Model layout: q (B, 1, Hq, D); caches (B, S, Hkv, D); lengths (B,).
 
     Returns (B, 1, Hq, D)."""
@@ -23,7 +24,9 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     qk = q.reshape(b, hkv, g, d)
     kk = k_cache.transpose(0, 2, 1, 3)
     vk = v_cache.transpose(0, 2, 1, 3)
-    fn = decode_attention_pallas if use_kernel else decode_attention_ref
-    kwargs = {"interpret": interpret} if use_kernel else {}
-    out = fn(qk, kk, vk, lengths, window=window, **kwargs)
+    if use_kernel:
+        out = for_platform(
+            functools.partial(decode_attention_pallas, window=window), qk, kk, vk, lengths)
+    else:
+        out = decode_attention_ref(qk, kk, vk, lengths, window=window)
     return out.reshape(b, 1, hq, d)

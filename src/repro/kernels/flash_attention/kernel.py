@@ -89,12 +89,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(
     q, k, v, *, causal: bool = True, window: int = 0,
     block_q: int = 128, block_k: int = 128, scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """q: (B, Hkv, G, Sq, D);  k, v: (B, Hkv, Skv, D) -> (B, Hkv, G, Sq, D).
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; on TPU pass interpret=False.
+    ``interpret=True`` runs the kernel body on the CPU for validation;
+    ``repro.kernels.for_platform`` picks it from the lowering platform.
     """
     b, hkv, g, sq, d = q.shape
     _, _, skv, _ = k.shape

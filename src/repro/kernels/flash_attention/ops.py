@@ -5,15 +5,16 @@ import functools
 
 import jax
 
+from repro.kernels import for_platform
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 __all__ = ["flash_attention"]
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "use_kernel"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool = True, use_kernel: bool = True):
+                    use_kernel: bool = True):
     """Model layout: q (B, Sq, Hq, D); k, v (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -21,7 +22,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     qk = q.reshape(b, sq, hkv, g, d).transpose(0, 2, 3, 1, 4)
     kk = k.transpose(0, 2, 1, 3)
     vk = v.transpose(0, 2, 1, 3)
-    fn = flash_attention_pallas if use_kernel else flash_attention_ref
-    kwargs = {"interpret": interpret} if use_kernel else {}
-    out = fn(qk, kk, vk, causal=causal, window=window, **kwargs)
+    if use_kernel:
+        out = for_platform(
+            functools.partial(flash_attention_pallas, causal=causal, window=window), qk, kk, vk)
+    else:
+        out = flash_attention_ref(qk, kk, vk, causal=causal, window=window)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)

@@ -88,7 +88,7 @@ def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, fs_ref, state_scr, *,
         fs_ref[0] = state_scr[...]
 
 
-def ssd_pallas(xdt, dA, bm, cm, chunk: int = 128, interpret: bool = True):
+def ssd_pallas(xdt, dA, bm, cm, chunk: int = 128, *, interpret: bool):
     """xdt (B,S,H,P) f32; dA (B,S,H) f32; bm, cm (B,S,N) f32 (ngroups=1).
 
     Returns (y (B,S,H,P) f32, final_state (B,H,P,N) f32)."""

@@ -55,7 +55,7 @@ def _kernel(acc_ref, d_ref, e_ref, u_ref, sum_ref, acc_scr, *, penalty, nr, bloc
 
 def utility_scores_pallas(
     acc, deadlines, completions, penalty: str = "sigmoid",
-    block_r: int = 128, interpret: bool = True,
+    block_r: int = 128, *, interpret: bool,
 ):
     """acc (R, M); deadlines (R,); completions (R, M).
 

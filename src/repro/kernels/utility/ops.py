@@ -11,16 +11,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import for_platform
 from repro.kernels.utility.kernel import utility_scores_pallas
 from repro.kernels.utility.ref import utility_scores_ref
 
 __all__ = ["utility_scores"]
 
 
-@functools.partial(jax.jit, static_argnames=("penalty", "interpret", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("penalty", "use_kernel"))
 def utility_scores(
-    acc, deadlines, completions, penalty: str = "sigmoid",
-    interpret: bool = True, use_kernel: bool = True,
+    acc, deadlines, completions, penalty: str = "sigmoid", use_kernel: bool = True,
 ):
     """(U (R, M), column means (M,)) for one (requests x models) tile.
 
@@ -32,5 +32,6 @@ def utility_scores(
     d = jnp.asarray(deadlines, jnp.float32)
     if not use_kernel:
         return utility_scores_ref(acc, d, e, penalty)
-    u, sums = utility_scores_pallas(acc, d, e, penalty, interpret=interpret)
+    u, sums = for_platform(
+        functools.partial(utility_scores_pallas, penalty=penalty), acc, d, e)
     return u, sums / acc.shape[0]

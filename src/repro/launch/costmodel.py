@@ -45,8 +45,6 @@ def _cost_of(jitted, *args) -> dict:
     out = {"flops": 0.0, "bytes": 0.0, "collectives": {"total_bytes": 0}}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         out["flops"] = float(ca.get("flops", 0.0))
         out["bytes"] = float(ca.get("bytes accessed", 0.0))
     except Exception as e:

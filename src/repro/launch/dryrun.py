@@ -184,8 +184,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, force: bool = False) ->
             cost = {}
             try:
                 ca = compiled.cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
                 for k in ("flops", "bytes accessed", "transcendentals", "optimal_seconds"):
                     if k in ca:
                         cost[k] = float(ca[k])

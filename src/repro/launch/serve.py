@@ -29,6 +29,9 @@ def main(argv=None):
     ap.add_argument("--new-tokens", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import ARCHS
     from repro.core import Application, ModelProfile, Request, make_policy

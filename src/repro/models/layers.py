@@ -107,10 +107,12 @@ def embed_spec(vocab: int, d_model: int) -> dict:
 
 
 def embed_tokens(params, tokens, scale_by_dim: bool = False):
-    """Token embedding lookup via one-hot matmul (partitioner-friendly for
-    vocab-sharded tables on TPU; gather would de-shard the table)."""
+    """Token embedding lookup: one gather from the (vocab, d_model) table.
+
+    Under a mesh with ``Auto`` axes (``launch.mesh.make_mesh``) the
+    partitioner resolves a vocab-sharded table with a collective."""
     table = params["embedding"]
-    x = table[tokens]  # XLA lowers to gather; fine when vocab sharded w/ collective
+    x = table[tokens]
     if scale_by_dim:
         x = x * jnp.asarray(jnp.sqrt(table.shape[-1]), x.dtype)
     return x

@@ -87,9 +87,12 @@ def weight_bytes(cfg) -> int:
 def _affine_fit(obs: Sequence[tuple[int, float]]) -> tuple[float, float]:
     """(fixed_s, per_item_s) least-squares fit of (batch, seconds) points.
 
-    Degenerate inputs degrade gracefully: one distinct batch size yields
-    a flat model at the mean; negative slopes/intercepts (measurement
-    noise) are clamped so the affine model stays physical.
+    The model stays physical: every forward pays a positive fixed cost
+    (the weights are read once per pass) and no batch is cheaper than a
+    smaller one.  One distinct batch size, or a fit whose intercept is
+    not positive (noise steeper than the batch dependence it measures),
+    yields a flat model at the mean of the per-batch means; a negative
+    slope is clamped to zero.
     """
     if not obs:
         return 0.0, 0.0
@@ -101,11 +104,9 @@ def _affine_fit(obs: Sequence[tuple[int, float]]) -> tuple[float, float]:
     if len(bs) < 2:
         return ts[0], 0.0
     slope, intercept = np.polyfit(np.asarray(bs, float), np.asarray(ts, float), 1)
-    per_item = max(float(slope), 0.0)
-    fixed = max(float(intercept), 0.0)
-    if fixed == 0.0 and per_item == 0.0:
-        fixed = float(np.mean(ts))
-    return fixed, per_item
+    if intercept <= 0.0:
+        return float(np.mean(ts)), 0.0
+    return float(intercept), max(float(slope), 0.0)
 
 
 class ExecutorBackend:

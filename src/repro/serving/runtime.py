@@ -38,6 +38,7 @@ __all__ = [
     "LANE_NAMES",
     "PendingExecution",
     "ProcessLaneBackend",
+    "require_cpu_platform",
 ]
 
 # Lane strategies the pool can run its per-worker shares under (see
@@ -47,8 +48,24 @@ __all__ = [
 # coordination but forwards every batch forward to a spawned worker
 # process holding its own backend instance — host-side Python (padding,
 # fault polling, accounting) stays on the thread while the model forward
-# escapes the GIL entirely.
+# escapes the GIL entirely.  "process" runs on the CPU only: on a chip it
+# raises before spawning (see ``require_cpu_platform``).
 LANE_NAMES = ("serial", "thread", "process")
+
+
+def require_cpu_platform(what: str) -> None:
+    """Raise before ``what`` spawns processes that each build a JAX
+    backend, unless JAX runs on the CPU: an accelerator belongs to one
+    process at a time, and this process already holds it."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{what} starts processes that each need the device, but this "
+            f"process holds the {platform} device (one process per chip); "
+            "use it on the CPU only"
+        )
 
 
 class WindowQueue:
@@ -244,6 +261,7 @@ class ProcessLaneBackend(ExecutorBackend):
     """
 
     def __init__(self, template: ExecutorBackend):
+        require_cpu_platform('lane="process"')
         self.template = template
         self.variants = dict(template.variants)
         self.new_tokens = template.new_tokens

@@ -24,6 +24,7 @@ from repro.serving import (
     costmodel_profile,
     lm_latency_model,
 )
+from repro.serving.backends import _affine_fit
 
 
 # --------------------------------------------------------------- helpers
@@ -167,11 +168,29 @@ def test_compiled_backend_runs_real_forward_and_fits_latency():
                      class_token_ids=np.array([1, 2]))
     assert r.tokens.shape == (3, 2)
     assert len(r.predictions) == 3 and all(p in (0, 1) for p in r.predictions)
+    # The fit is fed known (batch, seconds) observations, not CPU timings.
+    for b, t in ((1, 0.004), (2, 0.006), (4, 0.010), (4, 0.010)):
+        be._record("m", b, t)
     fixed, per_item = be.affine("m")
-    assert fixed > 0 and per_item >= 0
+    assert fixed == pytest.approx(0.002) and per_item == pytest.approx(0.002)
     assert be.latency_model("m", 4) >= be.latency_model("m", 1)
     p = be.profile("m", [0.9, 0.8])
-    assert p.provenance == "realized" and p.latency_s > 0
+    assert p.provenance == "realized" and p.latency_s == pytest.approx(0.004)
+
+
+@pytest.mark.parametrize("obs", [
+    ((1, 0.005), (2, 0.020), (4, 0.040)),  # intercept < 0: noise-steep slope
+    ((1, 0.010), (2, 0.010)),  # flat: intercept > 0, slope 0
+    ((1, 0.012), (2, 0.008), (4, 0.009)),  # negative slope
+])
+def test_affine_fit_keeps_a_positive_fixed_term(obs):
+    """Whatever the noise does to the least-squares line, the fitted model
+    keeps fixed > 0, per_item >= 0, and stays within the observed range."""
+    fixed, per_item = _affine_fit(obs)
+    assert fixed > 0 and per_item >= 0
+    ts = [t for _, t in obs]
+    for b, _ in obs:
+        assert min(ts) - 1e-12 <= fixed + per_item * b <= max(ts) + 1e-12
 
 
 def test_compiled_backend_continuous_batching_splits_reports():

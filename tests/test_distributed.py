@@ -114,8 +114,6 @@ def test_mini_dryrun_subprocess():
                         out_shardings=(p_sh, o_sh, None),
                         ).lower(model.abstract_params(), abstract_opt, {"tokens": tok}).compile()
             ca = c.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # older jax: list of dicts
-                ca = ca[0] if ca else {}
             out["train_flops"] = float(ca.get("flops", 0))
         # decode
         pol = make_policy(cfg, "decode", mesh)

@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode on the CPU) vs pure-jnp oracle."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -35,7 +35,7 @@ def test_flash_attention_sweep(b, s, hq, hkv, d, window, dtype):
     q = jnp.asarray(rng.normal(size=(b, s, hq, d)), dtype)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
-    out_k = flash_attention(q, k, v, window=window, interpret=True)
+    out_k = flash_attention(q, k, v, window=window)
     out_r = model_flash(
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
         causal=True, window=window, q_chunk=max(s // 4, 16), kv_chunk=max(s // 4, 16),
@@ -52,10 +52,10 @@ def test_flash_attention_causality():
     q = jnp.asarray(rng.normal(size=(1, 64, 2, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 64, 2, 16)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(1, 64, 2, 16)), jnp.float32)
-    out1 = flash_attention(q, k, v, interpret=True)
+    out1 = flash_attention(q, k, v)
     k2 = k.at[:, 40:].set(999.0)
     v2 = v.at[:, 40:].set(-999.0)
-    out2 = flash_attention(q, k2, v2, interpret=True)
+    out2 = flash_attention(q, k2, v2)
     np.testing.assert_allclose(out1[:, :40], out2[:, :40], atol=1e-6)
 
 
@@ -78,7 +78,8 @@ def test_decode_attention_sweep(b, hkv, g, s, d, window, block_k, dtype):
     k = jnp.asarray(rng.normal(size=(b, hkv, s, d)), dtype)
     v = jnp.asarray(rng.normal(size=(b, hkv, s, d)), dtype)
     lengths = jnp.asarray(rng.integers(max(window, 1), s + 1, size=b), jnp.int32)
-    o_k = decode_attention_pallas(q, k, v, lengths, window=window, block_k=block_k)
+    o_k = decode_attention_pallas(q, k, v, lengths, window=window, block_k=block_k,
+                                  interpret=True)
     o_r = decode_attention_ref(
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
         lengths, window=window,
@@ -94,10 +95,10 @@ def test_decode_respects_length_mask():
     q = jnp.asarray(rng.normal(size=(1, 1, 2, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 1, 64, 16)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(1, 1, 64, 16)), jnp.float32)
-    o1 = decode_attention_pallas(q, k, v, jnp.asarray([32]), block_k=16)
+    o1 = decode_attention_pallas(q, k, v, jnp.asarray([32]), block_k=16, interpret=True)
     k2 = k.at[:, :, 32:].set(555.0)
     v2 = v.at[:, :, 32:].set(-555.0)
-    o2 = decode_attention_pallas(q, k2, v2, jnp.asarray([32]), block_k=16)
+    o2 = decode_attention_pallas(q, k2, v2, jnp.asarray([32]), block_k=16, interpret=True)
     np.testing.assert_allclose(o1, o2, atol=1e-6)
 
 

@@ -46,3 +46,22 @@ def test_serve_launcher():
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "mean utility" in proc.stdout
     assert "batch[" in proc.stdout
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the fixed .jax_cache/ in the checkout."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "from_env"))
+    assert enable_compile_cache(tmp_path) == str(tmp_path / "from_env")
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert enable_compile_cache(tmp_path) == str(tmp_path / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -154,6 +154,22 @@ def test_unknown_lane_rejected():
                      lane="rocket")
 
 
+def test_process_lane_refused_on_an_accelerator(monkeypatch):
+    # A chip belongs to one process: with JAX on a TPU the process lane
+    # raises before it spawns a child that would need the device.
+    import multiprocessing
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    backend = SimulatedBackend(PROFILES, occupancy="none")
+    before = set(multiprocessing.active_children())
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        ExecutorPool([Worker(0)], backend_factory=lambda: backend.spawn(),
+                     lane="process")
+    assert set(multiprocessing.active_children()) <= before
+
+
 def test_executor_pool_lifecycle():
     backend = SimulatedBackend(PROFILES, occupancy="none")
     pool = ExecutorPool([Worker(0), Worker(1)],

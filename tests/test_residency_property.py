@@ -168,12 +168,11 @@ def test_compiled_touch_matches_numpy_form():
     """The jitted ``pipeline._touch_residency`` is the same rule as the
     numpy ``touch_lru_array`` on random sequences (including oversize)."""
     jax = pytest.importorskip("jax")
-    from jax.experimental import enable_x64
 
     from repro.core.pipeline import _touch_residency
 
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         jit_touch = jax.jit(_touch_residency)
         for trial in range(20):
             n = int(rng.integers(1, 6))

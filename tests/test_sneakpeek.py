@@ -200,3 +200,25 @@ def test_loose_deadlines_pick_max_estimated_accuracy():
         acc_chosen = estimate_accuracy(r, app, chosen, "sharpened")
         acc_sc = estimate_accuracy(r, app, sc, "sharpened")
         assert acc_chosen >= acc_sc - 0.15  # group-mean selection tolerance
+
+
+def test_knn_kernel_error_propagates_from_auto_backend(monkeypatch):
+    """A failing k-NN kernel surfaces; it never comes back as numpy votes."""
+    from repro.kernels.knn import ops as knn_ops
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("kernel failed to lower")
+
+    monkeypatch.setattr(knn_ops, "knn_class_votes", broken)
+    spec = APP_SPECS["fall_detection"]
+    x, y = make_dataset(spec, 100, np.random.default_rng(0))
+    sp = KNNSneakPeek(x, y, spec.num_classes, k=5, backend="auto", seed=1)
+    with pytest.raises(RuntimeError, match="kernel failed to lower"):
+        sp._votes(x[:4])
+
+
+def test_knn_unknown_backend_is_refused():
+    spec = APP_SPECS["fall_detection"]
+    x, y = make_dataset(spec, 50, np.random.default_rng(0))
+    with pytest.raises(ValueError, match="unknown backend"):
+        KNNSneakPeek(x, y, spec.num_classes, backend="faiss")

@@ -255,14 +255,8 @@ def test_compressed_psum_under_shard_map():
     def f(g, e):
         return compressed_psum_tree(g, e, axis_name="pod")
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        smap, relax = jax.shard_map, {"check_vma": False}
-    else:  # older jax: experimental namespace, check_rep kwarg
-        from jax.experimental.shard_map import shard_map as smap
-
-        relax = {"check_rep": False}
-    out, new_ef = smap(
-        f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), **relax
+    out, new_ef = jax.shard_map(
+        f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )(g, ef)
     np.testing.assert_allclose(np.asarray(out["w"]), np.ones((2, 8)), atol=1e-2)
 
