@@ -1,0 +1,7 @@
+"""``prefill_s`` per served forward (a fused run counted once), in ms."""
+
+
+def read(rec: dict):
+    """Mean over the window's forwards, or None."""
+    f = rec["forwards"]
+    return 1e3 * sum(x["prefill_s"] for x in f) / len(f) if f else None
