@@ -443,7 +443,7 @@ def run_executor(n_requests=16, new_tokens=2):
     ``CompiledBackend`` (bucketed jitted forwards + continuous batching)
     and ``CostModelBackend`` (roofline census, no device execution) — on
     reduced-size registry configs.  Reports per-backend window wall time
-    (``ServeStats.wall_s`` over executed windows) and the
+    (``ServeStats.exec_wall_s`` over executed windows) and the
     realized-vs-profiled latency ratio: summed ``ExecutionReport``
     seconds over the schedule's committed ``est_latency_s`` for the same
     batches (the drift PR 6's EWMA corrects, here end-to-end per
@@ -548,7 +548,7 @@ def run_executor(n_requests=16, new_tokens=2):
             "served": served,
             "windows": stats.windows,
             "swaps": stats.swaps,
-            "window_wall_s": stats.wall_s / max(stats.windows, 1),
+            "window_wall_s": stats.exec_wall_s / max(stats.windows, 1),
             "realized_s": realized,
             "profiled_s": profiled,
             "realized_over_profiled": realized / profiled if profiled else None,

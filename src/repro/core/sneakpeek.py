@@ -27,6 +27,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.accuracy import ModelProfile, confusion_with_accuracy, recalls_from_confusion
 from repro.core.dirichlet import posterior_mean_batch
 
@@ -131,6 +132,10 @@ class KNNSneakPeek(SneakPeekModel):
     def _votes(self, queries: np.ndarray) -> np.ndarray:
         """(B, num_classes) vote counts for a batch of queries."""
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+        with tracing.span("ingest.knn", model=self.name, rows=queries.shape[0]):
+            return self._search(queries)
+
+    def _search(self, queries: np.ndarray) -> np.ndarray:
         if self.backend != "numpy":
             from repro.kernels.knn import ops as knn_ops
 

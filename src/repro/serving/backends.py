@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.accuracy import ModelProfile
 from repro.models import LM
 from repro.models.kvcache import cache_bytes
@@ -119,6 +120,9 @@ class ExecutorBackend:
     """
 
     provenance: str = "profiled"
+    # Forwards that ran a shape this backend had not run before (each a
+    # compile on a jitting backend); only ``CompiledBackend`` counts them.
+    cold_forwards: int = 0
 
     def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4):
         self.variants = dict(variants)
@@ -189,6 +193,20 @@ class ExecutorBackend:
         )
 
 
+def _programs(model: LM, new_tokens: int, donate_cache: bool):
+    """The jitted prefill and decode step of one model, as named
+    functions so their modules read ``jit_prefill`` and
+    ``jit_decode_step`` in a profiler trace."""
+
+    def prefill(params, tokens):
+        return model.prefill(params, tokens, max_len=tokens.shape[1] + new_tokens)
+
+    def decode_step(params, cache, tokens):
+        return model.decode_step(params, cache, tokens)
+
+    return jax.jit(prefill), jax.jit(decode_step, donate_argnums=(1,) if donate_cache else ())
+
+
 class ProfiledBackend(ExecutorBackend):
     """Today's accounting path, extracted from the pre-refactor
     ``LMExecutor`` with bit-identical defaults: lazy ``LM`` construction
@@ -213,43 +231,47 @@ class ProfiledBackend(ExecutorBackend):
             model = LM(cfg)
             self._models[name] = model
             self._params[name] = model.init(seed)
-            self._prefill_jit[name] = jax.jit(
-                lambda p, t, m=model: m.prefill(p, t, max_len=t.shape[1] + self.new_tokens)
-            )
-            self._decode_jit[name] = jax.jit(lambda p, c, t, m=model: m.decode_step(p, c, t))
+            self._prefill_jit[name], self._decode_jit[name] = _programs(
+                model, self.new_tokens, donate_cache=False)
         return self._models[name], self._params[name]
 
     def run_batch(self, model_name: str, prompts: np.ndarray, request_ids: list,
                   class_token_ids: Optional[np.ndarray] = None) -> ExecutionReport:
         """prompts: (B, S) int32 (pre-padded)."""
         model, params = self._get(model_name)
-        t0 = time.perf_counter()
-        logits, cache = self._prefill_jit[model_name](params, jnp.asarray(prompts))
-        logits.block_until_ready()
-        t1 = time.perf_counter()
-        toks = []
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        preds = None
-        if class_token_ids is not None:
-            option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
-            preds = list(np.argmax(option_logits, axis=-1))
-        toks.append(tok)
-        for _ in range(self.new_tokens - 1):
-            logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            toks.append(tok)
-        tok.block_until_ready()
-        t2 = time.perf_counter()
-        self._record(model_name, prompts.shape[0], t2 - t0)
+        b = prompts.shape[0]
+        with tracing.span("exec.forward", model=model_name, rows=b, padded=b,
+                          rids=request_ids):
+            t0 = time.perf_counter()
+            with tracing.span("exec.prefill"):
+                logits, cache = self._prefill_jit[model_name](params, jnp.asarray(prompts))
+                logits.block_until_ready()
+            t1 = time.perf_counter()
+            with tracing.span("exec.decode"):
+                toks = []
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                preds = None
+                if class_token_ids is not None:
+                    option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
+                    preds = list(np.argmax(option_logits, axis=-1))
+                toks.append(tok)
+                for _ in range(self.new_tokens - 1):
+                    logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    toks.append(tok)
+                tok.block_until_ready()
+            t2 = time.perf_counter()
+            self._record(model_name, b, t2 - t0)
+            tokens = np.stack([np.asarray(t) for t in toks], axis=1)
         return ExecutionReport(
             request_ids=request_ids,
             model=model_name,
-            batch_size=prompts.shape[0],
+            batch_size=b,
             swap_s=0.0,
             prefill_s=t1 - t0,
             decode_s=t2 - t1,
-            tokens=np.stack([np.asarray(t) for t in toks], axis=1),
-            predictions=preds if preds is not None else [None] * prompts.shape[0],
+            tokens=tokens,
+            predictions=preds if preds is not None else [None] * b,
         )
 
 
@@ -305,8 +327,9 @@ class CompiledBackend(ExecutorBackend):
         self._decode_jit: dict[str, Callable] = {}
         # Shapes already executed once (compiled): only their runs feed
         # the latency fit, so one-off jit compile time never pollutes the
-        # steady-state affine model.
+        # steady-state affine model; the others count as cold forwards.
         self._warm: set[tuple[str, int, int]] = set()
+        self.cold_forwards = 0
 
     def spawn(self) -> "CompiledBackend":
         """Fresh lane instance sharing the shape-bucketing hints."""
@@ -322,15 +345,11 @@ class CompiledBackend(ExecutorBackend):
             model = LM(cfg)
             self._models[name] = model
             self._params[name] = model.init(seed)
-            self._prefill_jit[name] = jax.jit(
-                lambda p, t, m=model: m.prefill(p, t, max_len=t.shape[1] + self.new_tokens)
-            )
             # Donating the cache lets XLA reuse its buffers in place
             # across decode steps (the cache pytree dominates activation
             # memory at serving batch sizes).
-            self._decode_jit[name] = jax.jit(
-                lambda p, c, t, m=model: m.decode_step(p, c, t), donate_argnums=(1,)
-            )
+            self._prefill_jit[name], self._decode_jit[name] = _programs(
+                model, self.new_tokens, donate_cache=True)
         return self._models[name], self._params[name]
 
     def _pad(self, prompts: np.ndarray) -> np.ndarray:
@@ -349,27 +368,30 @@ class CompiledBackend(ExecutorBackend):
         preds) for ALL padded rows and records the latency observation."""
         model, params = self._get(model_name)
         t0 = time.perf_counter()
-        logits, cache = self._prefill_jit[model_name](params, jnp.asarray(padded))
-        logits.block_until_ready()
+        with tracing.span("exec.prefill"):
+            logits, cache = self._prefill_jit[model_name](params, jnp.asarray(padded))
+            logits.block_until_ready()
         t1 = time.perf_counter()
-        toks = []
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        preds = None
-        if class_token_ids is not None:
-            option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
-            preds = np.argmax(option_logits, axis=-1)
-        toks.append(tok)
-        for _ in range(self.new_tokens - 1):
-            logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
+        with tracing.span("exec.decode"):
+            toks = []
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            preds = None
+            if class_token_ids is not None:
+                option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
+                preds = np.argmax(option_logits, axis=-1)
             toks.append(tok)
-        tok.block_until_ready()
+            for _ in range(self.new_tokens - 1):
+                logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                toks.append(tok)
+            tok.block_until_ready()
         t2 = time.perf_counter()
         key = (model_name, padded.shape[0], padded.shape[1])
         if key in self._warm:
             self._record(model_name, padded.shape[0], t2 - t0)
         else:
             self._warm.add(key)
+            self.cold_forwards += 1
         tokens = np.stack([np.asarray(t) for t in toks], axis=1)
         return t1 - t0, t2 - t1, tokens, preds
 
@@ -378,8 +400,11 @@ class CompiledBackend(ExecutorBackend):
         """One bucketed jitted forward for a scheduled batch; the report
         carries the UNPADDED rows (timing covers the padded shape)."""
         b = prompts.shape[0]
-        prefill_s, decode_s, tokens, preds = self._forward(
-            model_name, self._pad(prompts), class_token_ids)
+        padded = self._pad(prompts)
+        with tracing.span("exec.forward", model=model_name, rows=b,
+                          padded=padded.shape[0], rids=request_ids):
+            prefill_s, decode_s, tokens, preds = self._forward(
+                model_name, padded, class_token_ids)
         return ExecutionReport(
             request_ids=request_ids, model=model_name, batch_size=b,
             swap_s=0.0, prefill_s=prefill_s, decode_s=decode_s,
@@ -402,8 +427,11 @@ class CompiledBackend(ExecutorBackend):
         for p in prompt_list:
             merged[row:row + p.shape[0], :p.shape[1]] = p
             row += p.shape[0]
-        prefill_s, decode_s, tokens, preds = self._forward(
-            model_name, self._pad(merged), class_token_ids)
+        padded = self._pad(merged)
+        with tracing.span("exec.forward", model=model_name, rows=total,
+                          padded=padded.shape[0], rids=rid_lists):
+            prefill_s, decode_s, tokens, preds = self._forward(
+                model_name, padded, class_token_ids)
         reports = []
         row = 0
         for b, rids in zip(sizes, rid_lists):

@@ -21,6 +21,7 @@ from typing import Callable, Mapping, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.multiworker import Worker
 from repro.core.residency import evict_lru
 from repro.core.types import Request, Schedule, ScheduleEntry
@@ -685,6 +686,12 @@ class ExecutorPool:
         return {w: lane.swap_count for w, lane in sorted(self.lanes.items())}
 
     @property
+    def cold_forwards(self) -> int:
+        """Forwards, over every lane, that ran a shape their lane's
+        backend had not run before."""
+        return sum(lane.executor.backend.cold_forwards for lane in self.lanes.values())
+
+    @property
     def busy_s(self) -> dict[int, float]:
         """Per-worker busy seconds (scaled swap + prefill + decode)."""
         return {w: lane.busy_s for w, lane in sorted(self.lanes.items())}
@@ -781,7 +788,7 @@ class ExecutorPool:
             )
             return outcome, time.perf_counter()
 
-        return PendingExecution(self._coord.submit(_run), t0)
+        return PendingExecution(self._coord.submit(tracing.carry(_run)), t0)
 
     def execute_supervised(
         self,
@@ -849,7 +856,7 @@ class ExecutorPool:
         # order is immaterial (the join below is already sorted).
         futures = {
             wid: self._tp.submit(
-                self.lanes[wid].execute, by_worker[wid], prompt_fn,
+                tracing.carry(self.lanes[wid].execute), by_worker[wid], prompt_fn,
                 class_token_ids, until, on_dispatch,
                 injector, window, failures_by[wid] if supervised else None,
             )

@@ -21,6 +21,7 @@ from typing import Callable, Mapping, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.evaluation import evaluate
 from repro.core.scheduler import SchedulerPolicy, effective_apps, schedule_window
 from repro.core.streaming import StreamingState
@@ -40,7 +41,6 @@ class ServeStats:
     swaps: int = 0
     mean_utility: float = 0.0
     scheduling_overhead_s: float = 0.0
-    wall_s: float = 0.0
     # Per-worker busy seconds (swap + execution) accumulated at commit
     # time from the streaming state's replay, and the served makespan
     # (busiest worker's committed busy-until time).
@@ -81,6 +81,14 @@ class ServeStats:
     sched_wall_s: float = 0.0
     exec_wall_s: float = 0.0
     overlap_saved_s: float = 0.0
+    # Queueing: seconds the scheduled requests waited in the window queue
+    # (close time less arrival, summed) and how many were scheduled — a
+    # re-admitted request counts again at its next window.  Forwards that
+    # ran a shape their backend had not run before (a compile, on a
+    # jitting backend; ``CompiledBackend`` counts them).
+    queue_wait_s: float = 0.0
+    queued: int = 0
+    cold_forwards: int = 0
 
     @property
     def worker_utilization(self) -> dict:
@@ -381,32 +389,37 @@ class EdgeServer:
             # Fused data plane: batched ingest + compiled window program
             # (reused across windows), peeking the carried state.  Ingest
             # skips re-admitted requests (evidence drawn once).
-            self._pipeline.ingest(requests)
-            sched = self._pipeline.schedule(
-                requests, now, state=state,
-                lat_scale=lat_scale, worker_mask=mask,
-            )
+            with tracing.span("serve.ingest"):
+                self._pipeline.ingest(requests)
+            with tracing.span("serve.select"):
+                sched = self._pipeline.schedule(
+                    requests, now, state=state,
+                    lat_scale=lat_scale, worker_mask=mask,
+                )
             eff_apps = self._eff_apps
         else:
             if self.sneakpeeks:
-                attach_sneakpeek(requests, self.apps, self.sneakpeeks)
-            sched, eff_apps = schedule_window(
-                self.policy, requests, self._eff_apps, now,
-                workers=self.workers, state=state,
-                lat_scale=lat_scale, worker_mask=mask,
-            )
+                with tracing.span("serve.ingest"):
+                    attach_sneakpeek(requests, self.apps, self.sneakpeeks)
+            with tracing.span("serve.select"):
+                sched, eff_apps = schedule_window(
+                    self.policy, requests, self._eff_apps, now,
+                    workers=self.workers, state=state,
+                    lat_scale=lat_scale, worker_mask=mask,
+                )
         return sched, eff_apps, scale_fn
 
     def _commit_window(self, sched, eff_apps, now: float, scale_fn) -> object:
         """Evaluate a scheduled window against the committed state and
         fold the result into the aggregate stats (shared by both loop
         modes; identical math)."""
-        res = evaluate(
-            sched, eff_apps, now, acc_mode="oracle", state=self.state,
-            latency_scale=scale_fn,
-        )
-        self.stats.windows += 1
-        self._account(sched, res)
+        with tracing.span("serve.commit"):
+            res = evaluate(
+                sched, eff_apps, now, acc_mode="oracle", state=self.state,
+                latency_scale=scale_fn,
+            )
+            self.stats.windows += 1
+            self._account(sched, res)
         self.stats.scheduling_overhead_s += sched.scheduling_overhead_s
         # Per-worker utilization, fed from the streaming state at commit:
         # this window's realized busy seconds plus the pool's committed
@@ -418,83 +431,95 @@ class EdgeServer:
         )
         return res
 
+    def _count_queued(self, requests, now: float) -> None:
+        """Fold a window's scheduled requests into the queue-wait totals."""
+        self.stats.queued += len(requests)
+        self.stats.queue_wait_s += sum(now - r.arrival_s for r in requests)
+
+    def _readmit_due_retries(self, now: float) -> list:
+        """Backed-off retries whose ready time has arrived re-enter
+        through the queue like preempted work; returns them."""
+        due = [r for t, r in self._retry_ready if t <= now]
+        if due:
+            self._retry_ready = [(t, r) for t, r in self._retry_ready if t > now]
+            self.queue.readmit(sorted(due, key=lambda r: (r.arrival_s, r.rid)))
+        return due
+
+    def _copy_exec_counters(self) -> None:
+        """Copy the execution plane's swap and cold-forward counts (and a
+        pool's per-lane swaps and busy seconds) into the stats."""
+        if self.pool is not None:
+            self.stats.swaps = sum(self.pool.swap_counts.values())
+            self.stats.worker_swaps = dict(self.pool.swap_counts)
+            self.stats.pool_busy_s = dict(self.pool.busy_s)
+            self.stats.cold_forwards = self.pool.cold_forwards
+        else:
+            self.stats.swaps = self.executor.swaps.swap_count
+            self.stats.cold_forwards = self.executor.backend.cold_forwards
+
     def run_window(self, now: float):
         """Close the current window: (optionally) preempt, re-admit due
         retries, schedule (drift-corrected, health-masked), commit, and
         execute (supervised when the closed loop is on).  With
         ``overlap=True`` execution is dispatched asynchronously and the
         NEXT close schedules against a snapshot while it runs."""
-        if self.overlap:
-            return self._run_window_overlap(now)
         widx = self._window_index
         self._window_index += 1
+        win = tracing.window(widx)
+        with win:
+            close = self._run_window_overlap if self.overlap else self._run_window_sync
+            out = close(now, widx)
+            win.set_metadata(requests=0 if out is None else len(out["schedule"]))
+        return out
+
+    def _run_window_sync(self, now: float, widx: int):
+        """One close of the synchronous loop."""
         t_host0 = time.perf_counter()
-        if self.preempt:
-            self._preempt_window(now)
-        if self._retry_ready:
-            # Backed-off retries whose ready time has arrived re-enter
-            # through the queue like preempted work.
-            due = [r for t, r in self._retry_ready if t <= now]
-            if due:
-                self._retry_ready = [(t, r) for t, r in self._retry_ready if t > now]
-                self.queue.readmit(sorted(due, key=lambda r: (r.arrival_s, r.rid)))
-        requests = self.queue.drain_window(now)
+        with tracing.span("serve.drain"):
+            if self.preempt:
+                self._preempt_window(now)
+            self._readmit_due_retries(now)
+            requests = self.queue.drain_window(now)
         if not requests:
             self._close_health_window()
             return None
+        self._count_queued(requests, now)
         sched, eff_apps, scale_fn = self._schedule_requests(requests, now, self.state)
         res = self._commit_window(sched, eff_apps, now, scale_fn)
         self.stats.sched_wall_s += time.perf_counter() - t_host0
 
         reports = None
         outcome = None
-        if self._closed_loop and self.prompt_fn is not None:
-            # Supervised execution plane: per-batch fault isolation, lane
-            # deadline, and the failure records the retry loop consumes.
+        if self.executor is not None and self.prompt_fn is not None:
+            # With preemption on, only batches committed to start inside
+            # the upcoming window are dispatched (and marked so in the
+            # state); the rest stays backlogged, revisable at the next
+            # close.
+            until = now + self.queue.window_s if self.preempt else None
+            on_dispatch = self.state.mark_dispatched if self.preempt else None
             t1 = time.perf_counter()
-            outcome = self.pool.execute_supervised(
-                sched,
-                self.prompt_fn,
-                until=now + self.queue.window_s if self.preempt else None,
-                on_dispatch=self.state.mark_dispatched if self.preempt else None,
-                injector=self.injector,
-                window=widx,
-                timeout_s=self.lane_timeout_s,
-            )
-            self.stats.swaps = sum(self.pool.swap_counts.values())
-            self.stats.worker_swaps = dict(self.pool.swap_counts)
-            self.stats.pool_busy_s = dict(self.pool.busy_s)
-            dt = time.perf_counter() - t1
-            self.stats.wall_s += dt
-            self.stats.exec_wall_s += dt
-            self._absorb_outcome(outcome, sched, now)
-            reports = outcome.reports
-        elif self.pool is not None and self.prompt_fn is not None:
-            # Multi-worker execution plane: each lane runs its share of
-            # the placed schedule concurrently.  With preemption on, only
-            # batches committed to start inside the upcoming window are
-            # dispatched (and marked so in the state); the rest stays
-            # backlogged, revisable at the next close.
-            t1 = time.perf_counter()
-            reports = self.pool.execute_schedule(
-                sched,
-                self.prompt_fn,
-                until=now + self.queue.window_s if self.preempt else None,
-                on_dispatch=self.state.mark_dispatched if self.preempt else None,
-            )
-            self.stats.swaps = sum(self.pool.swap_counts.values())
-            self.stats.worker_swaps = dict(self.pool.swap_counts)
-            self.stats.pool_busy_s = dict(self.pool.busy_s)
-            dt = time.perf_counter() - t1
-            self.stats.wall_s += dt
-            self.stats.exec_wall_s += dt
-        elif self.executor is not None and self.prompt_fn is not None:
-            t1 = time.perf_counter()
-            reports = self.executor.execute_schedule(sched, self.prompt_fn)
-            self.stats.swaps = self.executor.swaps.swap_count
-            dt = time.perf_counter() - t1
-            self.stats.wall_s += dt
-            self.stats.exec_wall_s += dt
+            with tracing.span("serve.dispatch"):
+                if self._closed_loop:
+                    # Supervised execution plane: per-batch fault
+                    # isolation, lane deadline, and the failure records
+                    # the retry loop consumes.
+                    outcome = self.pool.execute_supervised(
+                        sched, self.prompt_fn, until=until, on_dispatch=on_dispatch,
+                        injector=self.injector, window=widx,
+                        timeout_s=self.lane_timeout_s,
+                    )
+                    reports = outcome.reports
+                elif self.pool is not None:
+                    # Multi-worker execution plane: each lane runs its
+                    # share of the placed schedule concurrently.
+                    reports = self.pool.execute_schedule(
+                        sched, self.prompt_fn, until=until, on_dispatch=on_dispatch)
+                else:
+                    reports = self.executor.execute_schedule(sched, self.prompt_fn)
+            self.stats.exec_wall_s += time.perf_counter() - t1
+            self._copy_exec_counters()
+            if outcome is not None:
+                self._absorb_outcome(outcome, sched, now)
         self._close_health_window()
         return {"schedule": sched, "eval": res, "reports": reports, "outcome": outcome}
 
@@ -516,7 +541,8 @@ class EdgeServer:
         Safe concurrently with lane execution: lanes only set dispatch
         marks (never timelines), scheduling only peeks the clone, and
         ``evaluate`` has not run — nothing commits here."""
-        requests = self.queue.drain_window(now)
+        with tracing.span("serve.drain"):
+            requests = self.queue.drain_window(now)
         if not requests:
             return None
         state_sig = self.state.signature()
@@ -539,17 +565,13 @@ class EdgeServer:
         pending, sched, now_k = self._inflight
         self._inflight = None
         outcome = pending.result()
-        self.stats.swaps = sum(self.pool.swap_counts.values())
-        self.stats.worker_swaps = dict(self.pool.swap_counts)
-        self.stats.pool_busy_s = dict(self.pool.busy_s)
-        dt = pending.finished_at - pending.started_at
-        self.stats.wall_s += dt
-        self.stats.exec_wall_s += dt
+        self._copy_exec_counters()
+        self.stats.exec_wall_s += pending.finished_at - pending.started_at
         if self._closed_loop:
             self._absorb_outcome(outcome, sched, now_k)
         self._close_health_window()
 
-    def _run_window_overlap(self, now: float):
+    def _run_window_overlap(self, now: float, widx: int):
         """One close of the double-buffered loop.
 
         Phases: (1) SPECULATE — drain and schedule this window against a
@@ -561,8 +583,6 @@ class EdgeServer:
         requests and recompute, which reproduces the synchronous
         decision exactly; (4) COMMIT + DISPATCH — evaluate against the
         real state and hand the schedule to the lanes asynchronously."""
-        widx = self._window_index
-        self._window_index += 1
         t_spec0 = time.perf_counter()
         spec = self._speculate(now) if self._inflight is not None else None
         t_spec1 = time.perf_counter()
@@ -576,13 +596,9 @@ class EdgeServer:
                 - max(t_spec0, pending_prev.started_at),
             )
         t_host0 = time.perf_counter()
-        withdrawn = self._preempt_window(now) if self.preempt else 0
-        due = []
-        if self._retry_ready:
-            due = [r for t, r in self._retry_ready if t <= now]
-            if due:
-                self._retry_ready = [(t, r) for t, r in self._retry_ready if t > now]
-                self.queue.readmit(sorted(due, key=lambda r: (r.arrival_s, r.rid)))
+        with tracing.span("serve.drain"):
+            withdrawn = self._preempt_window(now) if self.preempt else 0
+            due = self._readmit_due_retries(now)
         valid = (
             spec is not None
             and withdrawn == 0
@@ -595,12 +611,14 @@ class EdgeServer:
             sched, eff_apps = spec["sched"], spec["eff_apps"]
             scale_fn = self.health.scale_fn() if self.health is not None else None
         else:
-            if spec is not None:
-                # The speculative drain is rolled back through the queue;
-                # the re-drain below merges it with preempted/retried work
-                # under the same deterministic (arrival, rid) order.
-                self.queue.readmit(spec["requests"])
-            requests = self.queue.drain_window(now)
+            with tracing.span("serve.drain"):
+                if spec is not None:
+                    # The speculative drain is rolled back through the
+                    # queue; the re-drain merges it with preempted/retried
+                    # work under the same deterministic (arrival, rid)
+                    # order.
+                    self.queue.readmit(spec["requests"])
+                requests = self.queue.drain_window(now)
             if not requests:
                 self._close_health_window()
                 self.stats.sched_wall_s += (t_spec1 - t_spec0) + (
@@ -608,17 +626,19 @@ class EdgeServer:
                 return None
             sched, eff_apps, scale_fn = self._schedule_requests(
                 requests, now, self.state)
+        self._count_queued(requests, now)
         res = self._commit_window(sched, eff_apps, now, scale_fn)
-        pending = self.pool.execute_async(
-            sched,
-            self.prompt_fn,
-            until=now + self.queue.window_s if self.preempt else None,
-            on_dispatch=self.state.mark_dispatched if self.preempt else None,
-            injector=self.injector if self._closed_loop else None,
-            window=widx,
-            timeout_s=self.lane_timeout_s if self._closed_loop else None,
-            supervised=self._closed_loop,
-        )
+        with tracing.span("serve.dispatch"):
+            pending = self.pool.execute_async(
+                sched,
+                self.prompt_fn,
+                until=now + self.queue.window_s if self.preempt else None,
+                on_dispatch=self.state.mark_dispatched if self.preempt else None,
+                injector=self.injector if self._closed_loop else None,
+                window=widx,
+                timeout_s=self.lane_timeout_s if self._closed_loop else None,
+                supervised=self._closed_loop,
+            )
         self._inflight = (pending, sched, now)
         self.stats.sched_wall_s += (t_spec1 - t_spec0) + (
             time.perf_counter() - t_host0)
