@@ -196,9 +196,10 @@ def check_lm(backend, name: str, vocab: int) -> float:
 
     model, params = backend._get(name)
     prompts = jnp.asarray(np.stack([prompt_ids(rid, vocab) for rid in range(4)]))
-    logits, cache = backend._prefill_jit[name](params, prompts)
+    prefill = jax.jit(model.prefill, static_argnames="max_len")
+    logits, cache = prefill(params, prompts, max_len=PROMPT_LEN + backend.new_tokens)
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    step, _ = backend._decode_jit[name](params, cache, tok[:, None])
+    step, _ = jax.jit(model.decode_step)(params, cache, tok[:, None])
     full, _ = jax.jit(model.forward)(params, jnp.concatenate([prompts, tok[:, None]], 1))
     got = np.stack([np.asarray(logits, np.float32), np.asarray(step, np.float32)], 1)
     want = np.asarray(full[:, -2:], np.float32)

@@ -34,6 +34,7 @@ the drift correction (PR 6's realized/committed EWMA) is correcting.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Mapping, Optional, Sequence
@@ -123,6 +124,9 @@ class ExecutorBackend:
     # Forwards that ran a shape this backend had not run before (each a
     # compile on a jitting backend); only ``CompiledBackend`` counts them.
     cold_forwards: int = 0
+    # Blocking device-to-host waits (two per forward); only the backends
+    # that run real forwards count them.
+    host_syncs: int = 0
 
     def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4):
         self.variants = dict(variants)
@@ -193,21 +197,102 @@ class ExecutorBackend:
         )
 
 
+def _greedy(logits):
+    """The greedy pick: the first index of each row's largest logit."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _programs(model: LM, new_tokens: int, donate_cache: bool):
-    """The jitted prefill and decode step of one model, as named
-    functions so their modules read ``jit_prefill`` and
-    ``jit_decode_step`` in a profiler trace."""
+    """The jitted prefill and decode step of one model, each ending in the
+    greedy pick, so a forward's tokens stay on the device until it reads
+    them back once.  Named functions, so their modules read
+    ``jit_prefill`` and ``jit_decode_step`` in a profiler trace.
 
-    def prefill(params, tokens):
-        return model.prefill(params, tokens, max_len=tokens.shape[1] + new_tokens)
+    ``prefill(params, tokens, class_ids=None) -> (tok, cache, preds)``:
+    ``tok`` is the (B,) int32 pick from the last position's logits and
+    ``preds`` the pick among the logits of ``class_ids`` (None without
+    them).  ``decode_step(params, cache, tok) -> (tok, cache)`` feeds the
+    (B,) tokens back as one position each.
+    """
 
-    def decode_step(params, cache, tokens):
-        return model.decode_step(params, cache, tokens)
+    def prefill(params, tokens, class_ids=None):
+        logits, cache = model.prefill(params, tokens, max_len=tokens.shape[1] + new_tokens)
+        preds = None if class_ids is None else jnp.argmax(logits[:, class_ids], axis=-1)
+        return _greedy(logits), cache, preds
+
+    def decode_step(params, cache, tok):
+        logits, cache = model.decode_step(params, cache, tok[:, None])
+        return _greedy(logits), cache
 
     return jax.jit(prefill), jax.jit(decode_step, donate_argnums=(1,) if donate_cache else ())
 
 
-class ProfiledBackend(ExecutorBackend):
+class _ProgramBackend(ExecutorBackend):
+    """Base of the backends that run real forwards: one ``LM``, its
+    weights and its two programs (``_programs``) per variant, built on
+    first use, and the greedy forward over them."""
+
+    # Whether the decode step donates its cache (updated in place).
+    donate_cache = False
+
+    def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4):
+        super().__init__(variants, new_tokens)
+        self._models: dict[str, LM] = {}
+        self._params: dict[str, dict] = {}
+        self._prefill_jit: dict[str, Callable] = {}
+        self._decode_jit: dict[str, Callable] = {}
+        self.host_syncs = 0
+
+    def _get(self, name: str):
+        if name not in self._models:
+            cfg, seed = self.variants[name]
+            model = LM(cfg)
+            self._models[name] = model
+            self._params[name] = model.init(seed)
+            self._prefill_jit[name], self._decode_jit[name] = _programs(
+                model, self.new_tokens, donate_cache=self.donate_cache)
+        return self._models[name], self._params[name]
+
+    def _generate(self, model_name: str, prompts: np.ndarray,
+                  class_token_ids: Optional[np.ndarray]):
+        """Greedy decoding of ``new_tokens`` tokens for (B, S) ``prompts``;
+        returns (prefill_s, decode_s, tokens (B, new_tokens), preds or
+        None).  The host waits twice: for the prefill's token, which ends
+        ``prefill_s``, and for one readback of every token (and ``preds``)
+        once the last is ready, which ends ``decode_s``."""
+        _, params = self._get(model_name)
+        class_ids = (None if class_token_ids is None
+                     else jnp.asarray(class_token_ids, jnp.int32))
+        t0 = time.perf_counter()
+        with tracing.span("exec.prefill"):
+            tok, cache, preds = self._prefill_jit[model_name](
+                params, jnp.asarray(prompts), class_ids)
+            tok.block_until_ready()
+            self.host_syncs += 1
+        t1 = time.perf_counter()
+        with tracing.span("exec.decode"):
+            toks = [tok]
+            for _ in range(self.new_tokens - 1):
+                tok, cache = self._decode_jit[model_name](params, cache, tok)
+                toks.append(tok)
+            toks, preds = jax.device_get((toks, preds))
+            self.host_syncs += 1
+        t2 = time.perf_counter()
+        return t1 - t0, t2 - t1, np.stack(toks, axis=1), preds
+
+    @contextlib.contextmanager
+    def _forward_span(self, model_name: str, rows: int, padded: int, rids):
+        """The ``exec.forward`` span, given the host waits of its forward
+        as ``syncs`` once they are known."""
+        sp = tracing.span("exec.forward", model=model_name, rows=rows, padded=padded,
+                          rids=rids)
+        before = self.host_syncs
+        with sp:
+            yield
+            sp.set_metadata(syncs=self.host_syncs - before)
+
+
+class ProfiledBackend(_ProgramBackend):
     """Today's accounting path, extracted from the pre-refactor
     ``LMExecutor`` with bit-identical defaults: lazy ``LM`` construction
     per variant, jitted prefill (static ``max_len = prompt + new_tokens``)
@@ -218,60 +303,23 @@ class ProfiledBackend(ExecutorBackend):
 
     provenance = "profiled"
 
-    def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4):
-        super().__init__(variants, new_tokens)
-        self._models: dict[str, LM] = {}
-        self._params: dict[str, dict] = {}
-        self._prefill_jit: dict[str, Callable] = {}
-        self._decode_jit: dict[str, Callable] = {}
-
-    def _get(self, name: str):
-        if name not in self._models:
-            cfg, seed = self.variants[name]
-            model = LM(cfg)
-            self._models[name] = model
-            self._params[name] = model.init(seed)
-            self._prefill_jit[name], self._decode_jit[name] = _programs(
-                model, self.new_tokens, donate_cache=False)
-        return self._models[name], self._params[name]
-
     def run_batch(self, model_name: str, prompts: np.ndarray, request_ids: list,
                   class_token_ids: Optional[np.ndarray] = None) -> ExecutionReport:
         """prompts: (B, S) int32 (pre-padded)."""
-        model, params = self._get(model_name)
         b = prompts.shape[0]
-        with tracing.span("exec.forward", model=model_name, rows=b, padded=b,
-                          rids=request_ids):
-            t0 = time.perf_counter()
-            with tracing.span("exec.prefill"):
-                logits, cache = self._prefill_jit[model_name](params, jnp.asarray(prompts))
-                logits.block_until_ready()
-            t1 = time.perf_counter()
-            with tracing.span("exec.decode"):
-                toks = []
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                preds = None
-                if class_token_ids is not None:
-                    option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
-                    preds = list(np.argmax(option_logits, axis=-1))
-                toks.append(tok)
-                for _ in range(self.new_tokens - 1):
-                    logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
-                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    toks.append(tok)
-                tok.block_until_ready()
-            t2 = time.perf_counter()
-            self._record(model_name, b, t2 - t0)
-            tokens = np.stack([np.asarray(t) for t in toks], axis=1)
+        with self._forward_span(model_name, b, b, request_ids):
+            prefill_s, decode_s, tokens, preds = self._generate(
+                model_name, prompts, class_token_ids)
+            self._record(model_name, b, prefill_s + decode_s)
         return ExecutionReport(
             request_ids=request_ids,
             model=model_name,
             batch_size=b,
             swap_s=0.0,
-            prefill_s=t1 - t0,
-            decode_s=t2 - t1,
+            prefill_s=prefill_s,
+            decode_s=decode_s,
             tokens=tokens,
-            predictions=preds if preds is not None else [None] * b,
+            predictions=list(preds) if preds is not None else [None] * b,
         )
 
 
@@ -285,7 +333,7 @@ def _bucket_seq(s: int, multiple: int) -> int:
     return max(((s + multiple - 1) // multiple) * multiple, multiple)
 
 
-class CompiledBackend(ExecutorBackend):
+class CompiledBackend(_ProgramBackend):
     """Real jitted forwards over registry models, serving-shaped.
 
     Differences from ``ProfiledBackend`` (which times whatever shape the
@@ -313,6 +361,10 @@ class CompiledBackend(ExecutorBackend):
     """
 
     provenance = "realized"
+    # Donating the cache lets XLA reuse its buffers in place across decode
+    # steps (the cache pytree dominates activation memory at serving batch
+    # sizes).
+    donate_cache = True
 
     def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4,
                  seq_multiple: int = 8, batch_hint: int = 8,
@@ -321,10 +373,6 @@ class CompiledBackend(ExecutorBackend):
         self.seq_multiple = int(seq_multiple)
         self.batch_hint = int(batch_hint)
         self.max_len_hint = max_len_hint
-        self._models: dict[str, LM] = {}
-        self._params: dict[str, dict] = {}
-        self._prefill_jit: dict[str, Callable] = {}
-        self._decode_jit: dict[str, Callable] = {}
         # Shapes already executed once (compiled): only their runs feed
         # the latency fit, so one-off jit compile time never pollutes the
         # steady-state affine model; the others count as cold forwards.
@@ -338,19 +386,6 @@ class CompiledBackend(ExecutorBackend):
             seq_multiple=self.seq_multiple, batch_hint=self.batch_hint,
             max_len_hint=self.max_len_hint,
         )
-
-    def _get(self, name: str):
-        if name not in self._models:
-            cfg, seed = self.variants[name]
-            model = LM(cfg)
-            self._models[name] = model
-            self._params[name] = model.init(seed)
-            # Donating the cache lets XLA reuse its buffers in place
-            # across decode steps (the cache pytree dominates activation
-            # memory at serving batch sizes).
-            self._prefill_jit[name], self._decode_jit[name] = _programs(
-                model, self.new_tokens, donate_cache=True)
-        return self._models[name], self._params[name]
 
     def _pad(self, prompts: np.ndarray) -> np.ndarray:
         b, s = prompts.shape
@@ -366,34 +401,15 @@ class CompiledBackend(ExecutorBackend):
                  class_token_ids: Optional[np.ndarray]):
         """One bucketed forward; returns (prefill_s, decode_s, tokens,
         preds) for ALL padded rows and records the latency observation."""
-        model, params = self._get(model_name)
-        t0 = time.perf_counter()
-        with tracing.span("exec.prefill"):
-            logits, cache = self._prefill_jit[model_name](params, jnp.asarray(padded))
-            logits.block_until_ready()
-        t1 = time.perf_counter()
-        with tracing.span("exec.decode"):
-            toks = []
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            preds = None
-            if class_token_ids is not None:
-                option_logits = np.asarray(logits)[:, np.asarray(class_token_ids)]
-                preds = np.argmax(option_logits, axis=-1)
-            toks.append(tok)
-            for _ in range(self.new_tokens - 1):
-                logits, cache = self._decode_jit[model_name](params, cache, tok[:, None])
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                toks.append(tok)
-            tok.block_until_ready()
-        t2 = time.perf_counter()
+        prefill_s, decode_s, tokens, preds = self._generate(
+            model_name, padded, class_token_ids)
         key = (model_name, padded.shape[0], padded.shape[1])
         if key in self._warm:
-            self._record(model_name, padded.shape[0], t2 - t0)
+            self._record(model_name, padded.shape[0], prefill_s + decode_s)
         else:
             self._warm.add(key)
             self.cold_forwards += 1
-        tokens = np.stack([np.asarray(t) for t in toks], axis=1)
-        return t1 - t0, t2 - t1, tokens, preds
+        return prefill_s, decode_s, tokens, preds
 
     def run_batch(self, model_name: str, prompts: np.ndarray, request_ids: list,
                   class_token_ids: Optional[np.ndarray] = None) -> ExecutionReport:
@@ -401,8 +417,7 @@ class CompiledBackend(ExecutorBackend):
         carries the UNPADDED rows (timing covers the padded shape)."""
         b = prompts.shape[0]
         padded = self._pad(prompts)
-        with tracing.span("exec.forward", model=model_name, rows=b,
-                          padded=padded.shape[0], rids=request_ids):
+        with self._forward_span(model_name, b, padded.shape[0], request_ids):
             prefill_s, decode_s, tokens, preds = self._forward(
                 model_name, padded, class_token_ids)
         return ExecutionReport(
@@ -428,8 +443,7 @@ class CompiledBackend(ExecutorBackend):
             merged[row:row + p.shape[0], :p.shape[1]] = p
             row += p.shape[0]
         padded = self._pad(merged)
-        with tracing.span("exec.forward", model=model_name, rows=total,
-                          padded=padded.shape[0], rids=rid_lists):
+        with self._forward_span(model_name, total, padded.shape[0], rid_lists):
             prefill_s, decode_s, tokens, preds = self._forward(
                 model_name, padded, class_token_ids)
         reports = []

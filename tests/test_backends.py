@@ -207,6 +207,88 @@ def test_compiled_backend_continuous_batching_splits_reports():
     assert total > 0
 
 
+def _prompts(rows, seq=8, vocab=128, seed=0):
+    return np.random.default_rng(seed).integers(0, vocab, (rows, seq)).astype(np.int32)
+
+
+@pytest.mark.parametrize("new_tokens", [2, 4])
+@pytest.mark.parametrize("arch", ["granite-8b", "mamba2-130m"])
+def test_compiled_backend_tokens_equal_greedy_generate(arch, new_tokens):
+    """Tokens picked inside the jitted programs are LM.generate's greedy
+    tokens; 3 rows run padded to 4."""
+    import jax.numpy as jnp
+
+    from repro.models import LM
+
+    cfg = _reduced(arch)
+    be = CompiledBackend({"m": (cfg, 0)}, new_tokens=new_tokens, seq_multiple=8)
+    prompts = _prompts(3, vocab=cfg.vocab_size)
+    r = be.run_batch("m", prompts, [0, 1, 2])
+    lm = LM(cfg)
+    want = lm.generate(lm.init(0), jnp.asarray(prompts), new_tokens, temperature=0.0)
+    assert r.tokens.dtype == np.int32
+    np.testing.assert_array_equal(r.tokens, np.asarray(want)[:, -new_tokens:])
+
+
+def test_compiled_backend_class_preds_equal_host_argmax_with_a_tie():
+    """``preds`` is the first-index argmax over the gathered option logits
+    of the prefill, as the host's gather and argmax would give, a
+    duplicated option id (an exact tie) included."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = _reduced("tinyllama-1.1b")
+    be = CompiledBackend({"m": (cfg, 0)}, new_tokens=2, seq_multiple=8)
+    prompts = _prompts(4, vocab=cfg.vocab_size, seed=3)
+    model, params = be._get("m")
+    logits, _ = jax.jit(model.prefill, static_argnames="max_len")(
+        params, jnp.asarray(prompts), max_len=10)
+    logits = np.asarray(logits)
+    top = int(np.argmax(logits[0]))
+    low = int(np.argmin(logits[0]))
+    ids = np.array([low, top, top, (top + 1) % cfg.vocab_size])  # row 0: tie at 1 and 2
+    r = be.run_batch("m", prompts, [0, 1, 2, 3], class_token_ids=ids)
+    want = np.argmax(logits[:, ids], axis=-1)
+    assert want[0] == 1
+    assert [int(p) for p in r.predictions] == [int(w) for w in want]
+    np.testing.assert_array_equal(r.tokens[:, 0], np.argmax(logits, axis=-1))
+
+
+def test_profiled_and_compiled_backends_pick_the_same_tokens():
+    cfg = _reduced("mamba2-130m")
+    prompts = _prompts(4, vocab=cfg.vocab_size, seed=5)
+    ids = np.array([3, 7, 11])
+    a = ProfiledBackend({"m": (cfg, 0)}, new_tokens=3).run_batch(
+        "m", prompts, [0, 1, 2, 3], class_token_ids=ids)
+    b = CompiledBackend({"m": (cfg, 0)}, new_tokens=3, seq_multiple=8).run_batch(
+        "m", prompts, [0, 1, 2, 3], class_token_ids=ids)
+    np.testing.assert_array_equal(a.tokens, b.tokens)
+    assert [int(p) for p in a.predictions] == [int(p) for p in b.predictions]
+
+
+def test_compiled_backend_reuses_the_donated_cache_across_forwards():
+    cfg = _reduced("tinyllama-1.1b")
+    be = CompiledBackend({"m": (cfg, 0)}, new_tokens=4, seq_multiple=8)
+    prompts = _prompts(3, vocab=cfg.vocab_size, seed=7)
+    runs = [be.run_batch("m", prompts, [0, 1, 2]).tokens for _ in range(3)]
+    assert be.cold_forwards == 1
+    for toks in runs[1:]:
+        np.testing.assert_array_equal(toks, runs[0])
+
+
+@pytest.mark.parametrize("backend", [CompiledBackend, ProfiledBackend])
+def test_host_syncs_two_per_forward(backend):
+    be = backend({"m": (_reduced("mamba2-130m"), 0)}, new_tokens=3)
+    assert be.host_syncs == 0
+    prompts = np.ones((2, 8), np.int32)
+    for n in range(1, 4):
+        be.run_batch("m", prompts, [0, 1], class_token_ids=np.array([1, 2]) if n == 2 else None)
+        assert be.host_syncs == 2 * n
+    if backend is CompiledBackend:
+        be.run_batches("m", [prompts, prompts[:1]], [[0, 1], [2]])
+        assert be.host_syncs == 8
+
+
 def test_compiled_backend_model_bytes_includes_kv_cache():
     cfg = _reduced("tinyllama-1.1b")
     be = CompiledBackend({"m": (cfg, 0)}, new_tokens=2)

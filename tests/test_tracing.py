@@ -140,6 +140,24 @@ def test_server_span_tree_per_window(tmp_path, model_cfg):
     assert stats.windows == 3
 
 
+def test_forward_span_carries_its_host_syncs(tmp_path, model_cfg):
+    be = CompiledBackend({"m": (model_cfg, 0)}, new_tokens=3)
+    p = np.zeros((3, 8), np.int32)
+
+    def body():
+        be.run_batch("m", p, [0, 1, 2])
+        be.run_batches("m", [p, p[:1]], [[0, 1, 2], [3]])
+        be.run_batch("m", p, [0, 1, 2], class_token_ids=np.array([1, 2]))
+
+    _, spans = _traced(tmp_path, body)
+    fwds = sorted((s for s in spans if s[0] == "exec.forward"), key=lambda s: s[1])
+    assert [s[3]["syncs"] for s in fwds] == [2, 2, 2]
+    assert be.host_syncs == 6
+    for name in ("exec.prefill", "exec.decode"):
+        inner = [s for s in spans if s[0] == name]
+        assert len(inner) == 3 and all(any(_inside(s, f) for f in fwds) for s in inner)
+
+
 def test_overlapped_pool_lanes_carry_the_dispatching_window(tmp_path, model_cfg):
     be = CompiledBackend({"m": (model_cfg, 0)}, new_tokens=2)
     app = {"app": Application(name="app", models=[be.profile("m", [0.9, 0.8])],
