@@ -16,18 +16,14 @@
   by which a served token's reference logit lies below the reference's best
   at that position, in units of the standard deviation of the reference
   logits there.  Greedy decoding in the program's bf16 puts that gap near
-  0; a limit per kind of served model sits between the program's readings
-  and the float8 control's (see ``PERF.md``).
+  0; each architecture's limit (``GAP_LIMIT`` of its module in
+  ``bench/archs/``) sits between the program's readings and the float8
+  control's (see ``PERF.md``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-# Widest normalized gap allowed for a served token, per model kind.  Set
-# from TPU v5e readings of the program over a dozen seeds and more (largest:
-# transformer 0.0615, SSD 0.2006) and of the float8 control (smallest:
-# transformer 0.256, SSD 0.819); the readings are listed in PERF.md.
-GAP_LIMITS = {"ssd": 0.5, "transformer": 0.15}
 SAMPLE_PER_MODEL = 32
 
 

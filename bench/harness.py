@@ -2,9 +2,12 @@
 
 Everything a cell needs is found by name: the workload in ``BENCHMARK.json``
 names a configuration (``bench/configs/<file>``) and a traffic mix
-(``bench/traffic/<traffic>.json``), the mix names its generator
-(``bench/generators/<generator>.py``), and each per-layer metric has its
-reader (``bench/metrics/<name>.py``).
+(``bench/traffic/<traffic>.json``), each model group of the configuration
+names its architecture (``bench/archs/<arch>.py``), the mix names its
+generator (``bench/generators/<generator>.py``), and each per-layer metric
+has its reader (``bench/metrics/<name>.py``), which reads the record the
+run leaves: its windows and forwards, the profiler trace's device ops
+(``bench/trace.py``) and the program's spans (``bench/spans.py``).
 
 The load loop is the benchmark's own.  Requests are due on a seeded
 schedule; at every tick of ``window_s`` on the wall clock the loop hands
@@ -192,11 +195,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     ev = _events()
 
-    from bench import check, flops
-    from bench.models import load_config, to_model_config
+    from bench import check, flops, spans
+    from bench.models import arch, load_config
     from bench.serving import StampedBackend, TimedKNN, span
     from bench.utility import realized_utility
     from bench.weights import make_weights
+    from repro import tracing
     from repro.core import make_policy
     from repro.core.dirichlet import jeffreys_prior
     from repro.core.types import Application, Request
@@ -220,7 +224,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
     c0 = ev.compiles
     weights, mcfgs = {}, {}
     for salt, d in enumerate(roles.values()):
-        mcfgs[d.name] = to_model_config(d)
+        mcfgs[d.name] = arch(d).model_config(d)
         weights[d.name] = make_weights(d, seed, salt)
         want = jax.tree.map(lambda a: (a.shape, str(a.dtype)), LM(mcfgs[d.name]).abstract_params())
         got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), weights[d.name])
@@ -308,7 +312,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
             gc_pauses.append(time.perf_counter() - gc_t[0])
 
     gc.callbacks.append(_gc)
-    c_window = h_window = 0
+    c_window = h_window = cold0 = 0
     watch = stalls.Watch().start()
     t0 = time.perf_counter()
     setup_s = t0 + lead * w_s - t_start
@@ -316,6 +320,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         watch.progress = w
         if w == lead:
             c_window, h_window = ev.compiles, ev.hits
+            cold0 = server.stats.cold_forwards
         if w == tr_first:
             t_trace = time.perf_counter()
             opts = jax.profiler.ProfileOptions()
@@ -326,6 +331,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
             backend.trace = True
             for sp in sneaks.values():
                 sp.trace = True
+            tracing.enable(True)
         tick = t0 + (w + 1) * w_s
         with span("bench.wait", trace and tr_first <= w <= tr_last):
             delay = tick - time.perf_counter()
@@ -338,6 +344,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
                                   true_label=r["label"]))
         ing0 = sum(sp.seconds for sp in sneaks.values())
         sch0 = server.stats.sched_wall_s
+        qw0, q0 = server.stats.queue_wait_s, server.stats.queued
         f0 = len(backend.forwards)
         with span("bench.close", trace and tr_first <= w <= tr_last):
             out = server.run_window(close - t0)
@@ -350,10 +357,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
                 "ingest_s": sum(sp.seconds for sp in sneaks.values()) - ing0,
                 "sched_s": server.stats.sched_wall_s - sch0,
                 "exec_s": sum(f["prefill_s"] + f["decode_s"] for f in fw), "forwards": len(fw),
+                "queue_wait_s": server.stats.queue_wait_s - qw0,
+                "queued": server.stats.queued - q0,
             })
         if w == tr_last:
             win_ann.__exit__(None, None, None)
             jax.profiler.stop_trace()
+            tracing.enable(False)
             backend.trace = False
             for sp in sneaks.values():
                 sp.trace = False
@@ -412,7 +422,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         "forwards": [f for f in backend.forwards if t_first <= f["t"] < t_last],
         "requests": [{"model": served_by[r]} for r in sorted(meas_rids) if r in done],
         "roles": roles, "dims_by_model": dims_by_model,
-        "prompt_len": P, "new_tokens": T, "peaks": None, "trace": None,
+        "prompt_len": P, "new_tokens": T, "peaks": None, "trace": None, "spans": None,
+        "cold_forwards": server.stats.cold_forwards - cold0,
     }
 
     # -- free the program's state, then the checks
@@ -448,7 +459,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         sv = np.stack([tokens[r] for r in rids])
         key = f"gap_{role_of[name]}"
         gaps[name] = check.served_gap(d, weights[name], pr, sv, check.SAMPLE_PER_MODEL)
-        checks[key] = {"value": gaps[name], "limit": check.GAP_LIMITS[d.kind]}
+        checks[key] = {"value": gaps[name], "limit": arch(d).GAP_LIMIT}
         if control:
             ctl[key] = check.served_gap(d, weights[name], pr, sv, check.SAMPLE_PER_MODEL,
                                         quant=True)
@@ -464,7 +475,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         from bench.peaks import peaks_for
 
         red = trace_mod.reduce(trace_mod.load(trace_dir))
+        rec["spans"] = spans.reduce(spans.load(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(spans.idle_line(rec["spans"]))
         rec["trace"], rec["peaks"] = red, peaks_for(dev["kind"])
         for name, d in dims_by_model.items():
             b = max((f["padded"] for f in rec["forwards"] if f["model"] == name), default=0)

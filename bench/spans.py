@@ -1,11 +1,15 @@
 """Reduction of a profiler trace by the program's own spans.
 
 ``load`` reads the same ``.xplane.pb`` as ``bench/trace.py``: the program's
-host spans (``serve.*``, ``ingest.*``, ``exec.*``, which ``repro.tracing``
-writes while it is on) with their arguments, the device planes' op events
-and their module events (one per run of a compiled program, named after
-it: ``jit_decode_step``).  ``reduce`` works on plain tuples, so a test can
-feed it a synthetic trace.
+host spans with their arguments (every host event that carries a ``window``
+argument, which each span ``repro.tracing`` writes while it is on does,
+whatever its name), the device planes' op events and their module events
+(one per run of a compiled program, named after it: ``jit_decode_step``).
+``reduce`` works on plain tuples, so a test can feed it a synthetic trace.
+
+For every span name (``by_name``): how many spans, their host seconds, and
+the device-busy seconds inside them, so a reader of a new span needs no
+edit here.
 
 Per profiled window (one ``serve.window`` span each):
 
@@ -21,10 +25,11 @@ the innermost program span covering it.
 """
 from __future__ import annotations
 
+import bisect
+import itertools
 import statistics
 from pathlib import Path
 
-PROGRAM = ("serve.", "ingest.", "exec.")
 WINDOW = "serve.window"
 DECODE_MODULE = "jit_decode_step"
 OP_LINES = ("XLA Ops",)
@@ -49,8 +54,10 @@ def load(path) -> dict:
                         (e.name, float(e.start_ns), float(e.duration_ns)) for e in ln.events)
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
-                host.extend((e.name, float(e.start_ns), float(e.duration_ns), dict(e.stats))
-                            for e in ln.events if e.name.startswith(PROGRAM))
+                for e in ln.events:
+                    args = dict(e.stats)
+                    if "window" in args:
+                        host.append((e.name, float(e.start_ns), float(e.duration_ns), args))
     return {"ops": ops, "modules": modules, "host": host}
 
 
@@ -64,9 +71,22 @@ def _union(intervals):
     return out
 
 
-def _busy(merged, a: float, b: float) -> float:
-    """Length of the merged intervals inside [a, b)."""
-    return sum(max(0.0, min(e, b) - max(s, a)) for s, e in merged)
+class _Covered:
+    """Disjoint sorted intervals (``_union``'s), with the length of them
+    inside any [a, b) found by bisection."""
+
+    def __init__(self, merged):
+        self.starts = [s for s, _ in merged]
+        self.ends = [e for _, e in merged]
+        self.cum = list(itertools.accumulate((e - s for s, e in merged), initial=0.0))
+
+    def inside(self, a: float, b: float) -> float:
+        i = bisect.bisect_right(self.ends, a)  # the first interval ending after a
+        j = bisect.bisect_left(self.starts, b)  # past the last one starting before b
+        if i >= j:
+            return 0.0
+        return (self.cum[j] - self.cum[i] - max(0.0, a - self.starts[i])
+                - max(0.0, self.ends[j - 1] - b))
 
 
 def _idle_pieces(merged, a: float, b: float):
@@ -96,16 +116,18 @@ def _label_idle(pieces, spans):
 
 
 def reduce(trace: dict) -> dict | None:
-    """Per-window and per-forward readings, or None without program spans."""
+    """Per-window, per-forward and per-span-name readings, or None without
+    program spans."""
     host = [(n, s, s + d, a) for n, s, d, a in trace["host"]]
     wins = sorted((h for h in host if h[0] == WINDOW), key=lambda h: h[1])
     if not wins:
         return None
     ops = {p: _union((s, s + d) for _, s, d in evs) for p, evs in trace["ops"].items()}
     n_dev = len(ops)
+    busy = [_Covered(merged) for merged in ops.values()]
     decode = {p: _union((s, s + d) for n, s, d in evs if n.startswith(DECODE_MODULE))
               for p, evs in trace["modules"].items()}
-    decode = {p: iv for p, iv in decode.items() if iv}
+    decode = [_Covered(iv) for iv in decode.values() if iv]
     windows, idle_by = [], {}
     for _, w0, w1, args in wins:
         inside = [h for h in host if h[0] != WINDOW and w0 <= h[1] and h[2] <= w1]
@@ -130,11 +152,20 @@ def reduce(trace: dict) -> dict | None:
     forwards = []
     for _, f0, f1, args in sorted((h for h in host if h[0] == "exec.forward"),
                                   key=lambda h: h[1]):
-        dev = sum(_busy(iv, f0, f1) for iv in decode.values()) / len(decode) if decode else None
+        dev = sum(c.inside(f0, f1) for c in decode) / len(decode) if decode else None
         forwards.append({"model": args.get("model"), "rows": args.get("rows"),
                          "padded": args.get("padded"),
                          "decode_dev_s": None if dev is None else dev * 1e-9})
-    return {"windows": windows, "forwards": forwards, "idle_by_span": idle_by}
+    by_name = {}
+    for n, s, e, _ in host:
+        row = by_name.setdefault(n, {"count": 0, "host_s": 0.0, "busy_s": None})
+        row["count"] += 1
+        row["host_s"] += (e - s) * 1e-9
+        if busy:
+            inside = sum(c.inside(s, e) for c in busy) / n_dev * 1e-9
+            row["busy_s"] = (row["busy_s"] or 0.0) + inside
+    return {"windows": windows, "forwards": forwards, "idle_by_span": idle_by,
+            "by_name": by_name}
 
 
 def median_ms(red: dict | None, key: str):

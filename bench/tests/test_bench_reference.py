@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from bench import check, reference
-from bench.models import to_model_config
+from bench.models import arch
 from bench.tests.tiny import GELU, SSD, TRANSFORMER
 from bench.weights import make_weights
 
@@ -19,7 +19,7 @@ def test_reference_matches_program_in_f32(dims):
     from repro.models import LM
 
     w = make_weights(dims, 2**31 + 99, 0)
-    lm = LM(dataclasses.replace(to_model_config(dims), dtype="float32"))
+    lm = LM(dataclasses.replace(arch(dims).model_config(dims), dtype="float32"))
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, dims.vocab, (3, 11)), jnp.int32)
     with jax.default_matmul_precision("highest"):

@@ -66,6 +66,22 @@ def test_reduce_by_hand():
     assert line.startswith("spans: idle inside 2 closes") and "serve.window 11.000 ms" in line
 
 
+def test_by_name_by_hand():
+    t = _synthetic()
+    t["host"].append(("route.experts", 22 * MS, 13 * MS, {"window": 0}))  # a new prefix
+    by = spans.reduce(t)["by_name"]
+    assert set(by) == {h[0] for h in t["host"]}
+    assert by["route.experts"] == {"count": 1, "host_s": pytest.approx(0.013),
+                                   "busy_s": pytest.approx(0.012)}  # [22, 30) and [31, 35)
+    # [21, 55): ops 8 + 23 + 1 ms; [113, 138): 23 ms
+    assert by["exec.decode"] == {"count": 2, "host_s": pytest.approx(0.059),
+                                 "busy_s": pytest.approx(0.055)}
+    assert by["serve.window"] == {"count": 2, "host_s": pytest.approx(0.110),
+                                  "busy_s": pytest.approx(0.110 - 0.043)}
+    t["ops"] = {}
+    assert spans.reduce(t)["by_name"]["route.experts"]["busy_s"] is None
+
+
 def test_no_module_events_no_decode_reading():
     red = spans.reduce(_synthetic(modules=False))
     assert [f["decode_dev_s"] for f in red["forwards"]] == [None, None]
@@ -138,3 +154,43 @@ def test_recorded_program_spans_load(tmp_path):
     assert red["forwards"][0]["rows"] == 2
     if not t["ops"]:  # the CPU has no device plane: nothing to call idle
         assert w["idle_s"] is None and spans.median_ms(red, "idle_s") is None
+
+
+def test_load_keeps_the_spans_that_carry_a_window(tmp_path):
+    import jax
+
+    from repro import tracing
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    tracing.enable(True)
+    try:
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            with tracing.window(2):
+                with tracing.span("route.experts", layer=5):
+                    pass
+                with jax.profiler.TraceAnnotation("host.other"):
+                    pass
+    finally:
+        tracing.enable(False)
+    t = spans.load(tmp_path)
+    assert {h[0] for h in t["host"]} == {"serve.window", "route.experts"}
+    by = spans.reduce(t)["by_name"]
+    assert by["route.experts"]["count"] == 1 and "host.other" not in by
+
+
+def test_traced_run_hands_the_readers_spans_and_counters(monkeypatch):
+    import jax
+
+    from bench import harness, peaks
+    from bench.tests import tiny
+
+    # the CPU has no published peaks: lend it the chip's, for the readers that need them
+    monkeypatch.setitem(peaks.PEAKS, jax.devices()[0].device_kind, peaks.PEAKS["TPU v5 lite"])
+    res = harness.run("granite8b.paper", 2**31 + 515, 1.5, True, require_tpu=False,
+                      config_override=tiny.config(), traffic_override=tiny.traffic())
+    assert res["correct"], res["checks"]
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert m["select_ms"] > 0 and m["dispatch_host_ms"] > 0
+    assert m["queue_wait_ms"] > 0
+    assert m["cold_forwards"] == 0

@@ -5,7 +5,7 @@ import re
 import pytest
 
 from bench.harness import ROOT, load_cell, load_module
-from bench.models import load_config, param_layout, to_model_config
+from bench.models import arch, load_config
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -42,8 +42,9 @@ def test_config_layout_matches_program(name):
     for key in entry["reduced"]:
         assert key in cfg["reduced"]
     for dims in cfg["roles"].values():
-        want = jax.tree.map(lambda a: a.shape, LM(to_model_config(dims)).abstract_params())
-        got = jax.tree.map(lambda t: t[0], param_layout(dims),
+        mod = arch(dims)
+        want = jax.tree.map(lambda a: a.shape, LM(mod.model_config(dims)).abstract_params())
+        got = jax.tree.map(lambda t: t[0], mod.param_layout(dims),
                            is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[1], str))
         assert want == got
 

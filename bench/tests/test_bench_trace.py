@@ -38,6 +38,22 @@ def test_reduce_by_hand():
     assert gaps[2] == ["bench.wait", pytest.approx(0.010)]
 
 
+def test_ops_s_sums_every_op():
+    t = _synthetic()
+    # twelve more ops of 1 ms each, and a second run of one of them: past the top 10
+    t["devices"]["/device:TPU:0"] += [(f"op.{i}", (41 + i) * MS, 1 * MS) for i in range(12)]
+    t["devices"]["/device:TPU:0"].append(("op.11", 60 * MS, 2 * MS))
+    red = tr.reduce(t)
+    ops = red["ops_s"]
+    assert len(ops) == 3 + 12 and len(red["device_ops"]) == 10
+    assert ops["op.11"] == [pytest.approx(0.003), 2]
+    assert ops["op.0"] == [pytest.approx(0.001), 1]
+    assert ops["fusion.1"] == [pytest.approx(0.030), 2]
+    assert ops["late"] == [pytest.approx(0.001), 1]  # clipped to the window
+    assert sum(v[0] for v in ops.values()) == pytest.approx(0.030 + 0.015 + 0.001 + 0.014)
+    assert {n for n, _ in red["device_ops"]} < set(ops)
+
+
 def test_reduce_averages_over_devices():
     t = _synthetic()
     t["devices"]["/device:TPU:1"] = [("x", 0.0, 100 * MS)]

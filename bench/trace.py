@@ -9,7 +9,9 @@ Busy time is the union of the device's op intervals inside the traced
 window (the host span ``bench.window``), averaged over the devices that ran
 anything.  An idle gap is an interval of that window in which no op ran;
 each is named by the innermost ``bench.*`` span covering its midpoint, that
-is by what the host was doing.
+is by what the host was doing.  Every op's time inside the window and its
+count, by name and summed over the devices, is kept (``ops_s``), so a
+reader of a new kernel needs no edit here.
 """
 from __future__ import annotations
 
@@ -65,7 +67,8 @@ def _label(t: float, host) -> str:
 
 
 def reduce(trace: dict, top: int = 10) -> dict:
-    """busy_s, window_s, idle_share, and the top device ops and idle gaps."""
+    """busy_s, window_s, idle_share, every op's [seconds, count] by name
+    (``ops_s``), and the top device ops and idle gaps."""
     win = [(s, s + d) for n, s, d in trace["host"] if n == WINDOW_SPAN]
     if not win:
         raise ValueError(f"no {WINDOW_SPAN} span in the trace")
@@ -77,7 +80,9 @@ def reduce(trace: dict, top: int = 10) -> dict:
         busy_total += sum(e - s for s, e in merged)
         for name, s, d in evs:
             if s < w1 and s + d > w0:
-                ops[name] = ops.get(name, 0.0) + min(s + d, w1) - max(s, w0)
+                row = ops.setdefault(name, [0.0, 0])
+                row[0] += min(s + d, w1) - max(s, w0)
+                row[1] += 1
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
@@ -85,11 +90,13 @@ def reduce(trace: dict, top: int = 10) -> dict:
     n_dev = max(len(trace["devices"]), 1)
     window_s = (w1 - w0) * 1e-9
     busy_s = busy_total / n_dev * 1e-9
+    ops_s = {n: [t * 1e-9, k] for n, (t, k) in ops.items()}
     return {
         "busy_s": busy_s,
         "window_s": window_s,
         "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
-        "device_ops": [[n, t * 1e-9] for n, t in sorted(ops.items(), key=lambda x: -x[1])[:top]],
+        "ops_s": ops_s,
+        "device_ops": [[n, v[0]] for n, v in sorted(ops_s.items(), key=lambda x: -x[1][0])[:top]],
         "idle_gaps": [[_label(mid, trace["host"]), t * 1e-9]
                       for t, mid in sorted(gaps, key=lambda x: -x[0])[:top]],
     }

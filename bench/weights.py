@@ -1,6 +1,7 @@
 """Seeded bf16 weights for a served model, made on the device in one call.
 
-The pytree has the program's parameter layout (``models.param_layout``).
+The pytree has the program's parameter layout (the architecture module's
+``param_layout``, ``bench/archs/``).
 Stacked leaves are drawn one layer at a time inside the same program, so
 the f32 draw of a layer is the only transient beside the bf16 result.
 """
@@ -12,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.models import Dims, param_layout
+from bench.models import arch
 
 
 def seed_words(seed: int, salt: int) -> np.ndarray:
@@ -47,11 +48,11 @@ def _leaf(key, shape, law, std, stacked: bool):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _make(dims: Dims, words):
+def _make(dims, words):
     key = jax.random.key(0)
     for w in range(words.shape[0]):
         key = jax.random.fold_in(key, words[w])
-    layout = param_layout(dims)
+    layout = arch(dims).param_layout(dims)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(layout, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
     out = [
@@ -66,6 +67,6 @@ def _is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 3 and isinstance(x[1], str)
 
 
-def make_weights(dims: Dims, seed: int, salt: int):
+def make_weights(dims, seed: int, salt: int):
     """bf16 parameter pytree of ``dims`` from ``seed`` (``salt`` tells models apart)."""
     return _make(dims, jax.device_put(seed_words(seed, salt)))
