@@ -28,6 +28,8 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     rope_theta_local: float = 10_000.0
     window_size: int = 0
+    position_embedding: str = "rope"  # rope | none (NoPE: positions only through the mask)
+    attention_multiplier: float = 0.0  # softmax scale; 0 means 1/sqrt(head_dim)
     # mlp
     d_ff: int = 0
     activation: str = "swiglu"  # swiglu | geglu | gelu (non-gated)
@@ -38,14 +40,30 @@ class ModelConfig:
     embed_scale: bool = False
     logit_softcap: float = 0.0
     tie_embeddings: bool = True
+    # Granite's multipliers: embeddings x embedding_multiplier after the
+    # lookup, each mixer and FFN output x residual_multiplier before its
+    # residual add, logits / logits_scaling.  1.0 leaves the graph as it was.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
     # MoE
     num_experts: int = 0
     moe_top_k: int = 1
     moe_d_ff: int = 0
     dense_d_ff: int = 0  # d_ff of the dense interleave layers (defaults to d_ff)
     shared_expert: bool = False
+    shared_d_ff: int = 0  # width of the shared expert (defaults to moe_d_ff)
     capacity_factor: float = 1.25
     moe_group: int = 512  # GShard token-group size for dispatch
+    # gshard: softmax over all experts, top-k of those probabilities, grouped
+    # capacity dispatch (models/moe.py).  topk_softmax: softmax over the top-k
+    # router logits only, every routed token computed (no capacity), by the
+    # experts this chip holds: experts_here of them (0: all) from
+    # expert_offset on, the router still over all num_experts.
+    moe_router: str = "gshard"
+    experts_here: int = 0
+    expert_offset: int = 0
     # SSD (mamba-2)
     ssd_state: int = 0
     ssd_headdim: int = 64
@@ -70,6 +88,17 @@ class ModelConfig:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
         if self.num_experts and not self.moe_d_ff:
             object.__setattr__(self, "moe_d_ff", self.d_ff)
+        if self.position_embedding not in ("rope", "none"):
+            raise ValueError(f"bad position_embedding {self.position_embedding!r}")
+        if self.moe_router not in ("gshard", "topk_softmax"):
+            raise ValueError(f"bad moe_router {self.moe_router!r}")
+        if self.moe_router == "gshard" and self.held_experts != self.num_experts:
+            raise ValueError("a gshard MoE layer holds all of its experts")
+        if self.expert_offset < 0 or self.expert_offset + self.held_experts > self.num_experts:
+            raise ValueError(f"experts {self.expert_offset}..+{self.held_experts} are not "
+                             f"among the router's {self.num_experts}")
+        if self.num_experts and not 0 < self.moe_top_k <= self.num_experts:
+            raise ValueError(f"moe_top_k {self.moe_top_k} of {self.num_experts} experts")
         if not self.dense_d_ff:
             object.__setattr__(self, "dense_d_ff", self.d_ff)
         for p in self.pattern:
@@ -95,6 +124,22 @@ class ModelConfig:
 
     def layer_kind(self, i: int) -> str:
         return self.pattern[i % self.period]
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.experts_here or self.num_experts
+
+    @property
+    def counts_experts(self) -> bool:
+        """True when some layer is a held-expert MoE layer, which counts
+        the pairs it routes and the experts it reaches."""
+        return self.moe_router == "topk_softmax" and any(p.endswith(":moe") for p in self.pattern)
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared expert."""
+        return self.shared_d_ff or self.moe_d_ff
 
     @property
     def d_inner(self) -> int:  # ssd
@@ -139,9 +184,9 @@ class ModelConfig:
         elif ffn == "moe":
             mats = 3 if self.activation in ("swiglu", "geglu") else 2
             n += d * self.num_experts  # router
-            n += self.num_experts * mats * d * self.moe_d_ff
+            n += self.held_experts * mats * d * self.moe_d_ff
             if self.shared_expert:
-                n += mats * d * self.moe_d_ff
+                n += mats * d * self.shared_width
         return n
 
     def param_count(self) -> int:
@@ -166,7 +211,7 @@ class ModelConfig:
                 n += self.d_model * self.num_experts
                 n += self.moe_top_k * mats * self.d_model * self.moe_d_ff
                 if self.shared_expert:
-                    n += mats * self.d_model * self.moe_d_ff
+                    n += mats * self.d_model * self.shared_width
         return n
 
     def model_flops_per_token(self) -> float:
@@ -179,6 +224,7 @@ class ModelConfig:
         """Tiny same-family config for CPU smoke tests."""
         period = self.period
         layers = period * 2 + min(self.n_tail, 1)
+        routed = self.moe_router == "topk_softmax"
         return dataclasses.replace(
             self,
             name=f"{self.name}-reduced",
@@ -191,7 +237,12 @@ class ModelConfig:
             d_ff=128 if self.d_ff else 0,
             dense_d_ff=128 if self.dense_d_ff else 0,
             moe_d_ff=128 if self.num_experts else 0,
-            num_experts=min(self.num_experts, 4),
+            num_experts=min(self.num_experts, 8 if routed else 4),
+            # a share stays a share: 2 held of the 8
+            experts_here=min(self.experts_here, 2),
+            expert_offset=0,
+            moe_top_k=min(self.moe_top_k, 3) if routed else self.moe_top_k,
+            shared_d_ff=192 if self.shared_d_ff else 0,
             moe_group=16,
             # Drop-free capacity: C >= group * top_k, so prefill/decode match
             # the full forward exactly (capacity dropping is group-boundary

@@ -5,6 +5,7 @@ from repro.configs.base import ModelConfig
 from repro.configs.chameleon_34b import CONFIG as _chameleon
 from repro.configs.gemma3_4b import CONFIG as _gemma3
 from repro.configs.gemma_7b import CONFIG as _gemma7b
+from repro.configs.granite_4_0_h_small import CONFIG as _granite4h
 from repro.configs.granite_8b import CONFIG as _granite
 from repro.configs.llama4_maverick_400b_128e import CONFIG as _maverick
 from repro.configs.llama4_scout_17b_16e import CONFIG as _scout
@@ -26,6 +27,7 @@ ARCHS: dict[str, ModelConfig] = {
         _rgemma,
         _mamba2,
         _chameleon,
+        _granite4h,
     )
 }
 
@@ -41,6 +43,7 @@ ALIASES = {
     "recurrentgemma-9b": "recurrentgemma-9b",
     "mamba2-130m": "mamba2-130m",
     "chameleon-34b": "chameleon-34b",
+    "granite-4.0-h-small": "granite-4.0-h-small",
 }
 
 

@@ -425,10 +425,11 @@ def _project_qkv(params, x, cfg, positions, theta):
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"])
     v = jnp.einsum("btd,dhk->bthk", x, params["wv"])
     if "q_norm" in params:
-        q = rmsnorm(params["q_norm"], q)
-        k = rmsnorm(params["k_norm"], k)
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
+    if cfg.position_embedding == "rope":
+        q = rope(q, positions, theta)
+        k = rope(k, positions, theta)
     return q, k, v
 
 
@@ -444,6 +445,7 @@ def attn_forward(params, x, cfg, *, window: int = 0, theta: float = 10_000.0, po
     o = flash_attention(
         q, k, v, causal=True, window=window,
         q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
+        scale=cfg.attention_multiplier or None,
     )
     y = jnp.einsum("bthk,hkd->btd", o, params["wo"])
     return y, (k, v)
@@ -459,6 +461,7 @@ def attn_decode(params, x, kv_cache, pos, cfg, *, window: int = 0, theta: float 
     q, k, v = _project_qkv(params, x, cfg, positions, theta)
     k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), pos, axis=1)
     v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), pos, axis=1)
-    o = decode_attention(q, k_cache, v_cache, pos + 1, window=window)
+    o = decode_attention(q, k_cache, v_cache, pos + 1, window=window,
+                         scale=cfg.attention_multiplier or None)
     y = jnp.einsum("bthk,hkd->btd", o, params["wo"])
     return y, (k_cache, v_cache)

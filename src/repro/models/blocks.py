@@ -1,11 +1,16 @@
 """Per-layer blocks + the cache protocol shared by all mixer kinds.
 
 A block = pre-norm mixer + residual [+ pre-norm FFN + residual], with
-optional gemma3-style post-norms.  Three entry points per block:
+optional gemma3-style post-norms; each mixer and FFN output is scaled by
+``cfg.residual_multiplier`` before its residual add.  Three entry points
+per block:
 
   * ``block_full``    — full sequence, no cache (training / scoring)
   * ``block_prefill`` — full sequence, returns the decode cache
   * ``block_decode``  — one token, consumes + returns the cache
+
+The serving entry points also return the FFN's counters: a held-expert MoE
+layer's int32 (routed pairs, experts reached), None for every other layer.
 
 Cache layouts (per layer):
   attn:   {"k","v"}: (B, max_len, Hkv, Dh)     — absolute slots
@@ -55,7 +60,8 @@ def block_spec(cfg, kind: str) -> dict:
         if ffn == "mlp":
             spec["mlp"] = mlp_spec(d, cfg.dense_d_ff, gated)
         else:
-            spec["moe"] = moe_mod.moe_spec(d, cfg.num_experts, cfg.moe_d_ff, gated, cfg.shared_expert)
+            spec["moe"] = moe_mod.moe_spec(d, cfg.num_experts, cfg.moe_d_ff, gated,
+                                           cfg.shared_expert, cfg.experts_here, cfg.shared_d_ff)
         if cfg.post_norms:
             spec["mlp_post_norm"] = rmsnorm_spec(d)
     return spec
@@ -121,22 +127,34 @@ def _read_kv(cfg, cache, dtype):
 # ------------------------------------------------------------------ ffn part
 
 
+def _scaled(y, cfg):
+    """A mixer or FFN output as it joins the residual stream."""
+    return y * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else y
+
+
 def _apply_ffn(params, x, cfg, kind):
+    """The FFN half of a block: (x, aux, counts), ``aux`` the GShard layer's
+    load-balancing loss (else 0) and ``counts`` a held-expert layer's
+    counters (else None)."""
     ffn = _ffn(kind)
+    aux, counts = jnp.zeros((), jnp.float32), None
     if ffn == "none":
-        return x, jnp.zeros((), jnp.float32)
-    h = rmsnorm(params["mlp_norm"], x)
+        return x, aux, counts
+    h = rmsnorm(params["mlp_norm"], x, cfg.norm_eps)
     if ffn == "mlp":
-        y, aux = mlp(params["mlp"], h, cfg.activation), jnp.zeros((), jnp.float32)
+        y = mlp(params["mlp"], h, cfg.activation)
+    elif cfg.moe_router == "topk_softmax":
+        y, counts = moe_mod.held_forward(params["moe"], h, cfg)
     else:
         y, aux = moe_mod.moe_forward(params["moe"], h, cfg)
     if cfg.post_norms:
-        y = rmsnorm(params["mlp_post_norm"], y)
-    return x + y, aux
+        y = rmsnorm(params["mlp_post_norm"], y, cfg.norm_eps)
+    return x + _scaled(y, cfg), aux, counts
 
 
 def _post(params, y, cfg):
-    return rmsnorm(params["post_norm"], y) if cfg.post_norms else y
+    y = rmsnorm(params["post_norm"], y, cfg.norm_eps) if cfg.post_norms else y
+    return _scaled(y, cfg)
 
 
 # ------------------------------------------------------------------ full
@@ -145,7 +163,7 @@ def _post(params, y, cfg):
 def block_full(params, x, cfg, kind: str):
     """Training/scoring pass (no cache).  Returns (x, aux)."""
     mixer = _mixer(kind)
-    h = rmsnorm(params["pre_norm"], x)
+    h = rmsnorm(params["pre_norm"], x, cfg.norm_eps)
     if mixer in ("attn", "local"):
         window = cfg.window_size if mixer == "local" else 0
         y, _ = attn_mod.attn_forward(
@@ -156,7 +174,8 @@ def block_full(params, x, cfg, kind: str):
     else:  # ssd
         y, _ = ssd_mod.ssd_forward(params["ssd"], h, cfg)
     x = x + _post(params, y, cfg)
-    return _apply_ffn(params, x, cfg, kind)
+    x, aux, _ = _apply_ffn(params, x, cfg, kind)
+    return x, aux
 
 
 # ------------------------------------------------------------------ prefill
@@ -179,9 +198,9 @@ def _ring_from_prefill(k, window: int, max_len: int):
 def block_prefill(params, x, cfg, kind: str, max_len: int):
     """Full-sequence pass that also builds the decode cache.
 
-    Returns (x, cache, aux)."""
+    Returns (x, cache, counts)."""
     mixer = _mixer(kind)
-    h = rmsnorm(params["pre_norm"], x)
+    h = rmsnorm(params["pre_norm"], x, cfg.norm_eps)
     if mixer in ("attn", "local"):
         window = cfg.window_size if mixer == "local" else 0
         y, (k, v) = attn_mod.attn_forward(
@@ -203,8 +222,8 @@ def block_prefill(params, x, cfg, kind: str, max_len: int):
         y, (conv, state) = ssd_mod.ssd_forward(params["ssd"], h, cfg)
         cache = {"conv": conv, "state": state}
     x = x + _post(params, y, cfg)
-    x, aux = _apply_ffn(params, x, cfg, kind)
-    return x, cache, aux
+    x, _, counts = _apply_ffn(params, x, cfg, kind)
+    return x, cache, counts
 
 
 # ------------------------------------------------------------------ decode
@@ -212,9 +231,9 @@ def block_prefill(params, x, cfg, kind: str, max_len: int):
 
 def block_decode(params, x, cache, pos, cfg, kind: str):
     """One-token step.  x: (B, 1, D); pos: scalar int32 (position of the
-    new token).  Returns (x, new_cache, aux)."""
+    new token).  Returns (x, new_cache, counts)."""
     mixer = _mixer(kind)
-    h = rmsnorm(params["pre_norm"], x)
+    h = rmsnorm(params["pre_norm"], x, cfg.norm_eps)
     if mixer in ("attn", "local"):
         is_ring = mixer == "local"
         length = cache["k"].shape[1]
@@ -233,7 +252,8 @@ def block_decode(params, x, cache, pos, cfg, kind: str):
         }
         kc, vc = _read_kv(cfg, new_cache, q.dtype)
         valid = jnp.minimum(pos + 1, length) if is_ring else pos + 1
-        o = attn_mod.decode_attention(q, kc, vc, valid, window=0)
+        o = attn_mod.decode_attention(q, kc, vc, valid, window=0,
+                                      scale=cfg.attention_multiplier or None)
         y = jnp.einsum("bthk,hkd->btd", o, params["attn"]["wo"])
     elif mixer == "rglru":
         y, (conv, hs) = rglru_mod.rglru_decode_step(params["rec"], h, (cache["conv"], cache["h"]), cfg)
@@ -242,5 +262,5 @@ def block_decode(params, x, cache, pos, cfg, kind: str):
         y, (conv, state) = ssd_mod.ssd_decode_step(params["ssd"], h, (cache["conv"], cache["state"]), cfg)
         new_cache = {"conv": conv, "state": state}
     x = x + _post(params, y, cfg)
-    x, aux = _apply_ffn(params, x, cfg, kind)
-    return x, new_cache, aux
+    x, _, counts = _apply_ffn(params, x, cfg, kind)
+    return x, new_cache, counts

@@ -45,12 +45,15 @@ class LM:
     def loss(self, params, batch):
         return transformer.loss_fn(params, batch, self.cfg)
 
-    def prefill(self, params, tokens, max_len: int | None = None):
+    def prefill(self, params, tokens, max_len: int | None = None, counts: bool = False):
+        """(last logits, cache), and with ``counts`` the held-expert
+        counters (``transformer.prefill``)."""
         max_len = max_len or tokens.shape[1]
-        return transformer.prefill(params, tokens, self.cfg, max_len)
+        return transformer.prefill(params, tokens, self.cfg, max_len, counts)
 
-    def decode_step(self, params, cache, tokens):
-        return transformer.decode_step(params, cache, tokens, self.cfg)
+    def decode_step(self, params, cache, tokens, counts: bool = False):
+        """(logits, cache), and with ``counts`` the step's counters."""
+        return transformer.decode_step(params, cache, tokens, self.cfg, counts)
 
     def init_cache(self, batch: int, max_len: int, start_pos: int = 0):
         return kvcache.init_cache(self.cfg, batch, max_len, start_pos)

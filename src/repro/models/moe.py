@@ -1,4 +1,15 @@
-"""Routed MoE: GShard/Switch-style grouped capacity dispatch (top-k, EP-ready).
+"""Routed MoE: two layers, chosen by ``cfg.moe_router``.
+
+``moe_router="topk_softmax"`` (``held_forward``; granite-4.0-h): the router
+scores all ``num_experts``, the softmax runs over each token's top-k
+logits, and the layer holds a contiguous block of ``held_experts`` of them
+(from ``expert_offset``), as one chip of an expert-parallel deployment does.
+It computes its own experts' part of the result for every token routed to
+them (no capacity, nothing dropped) plus the shared expert, and counts the
+(token, held expert) pairs routed and the held experts reached.
+
+``moe_router="gshard"`` (``moe_forward``), the rest of this docstring:
+GShard/Switch-style grouped capacity dispatch (top-k, EP-ready).
 
 Tokens are split into groups of ``moe_group``; per (group, expert)
 capacity C = ceil(group * top_k / E * capacity_factor).  Dispatch/combine
@@ -21,22 +32,65 @@ from repro.distributed.sharding import shard_act
 from repro.models.layers import _act
 from repro.models.spec import P
 
-__all__ = ["moe_spec", "moe_forward"]
+__all__ = ["moe_spec", "moe_forward", "held_forward"]
 
 
-def moe_spec(d_model: int, num_experts: int, d_ff: int, gated: bool, shared: bool) -> dict:
+def moe_spec(d_model: int, num_experts: int, d_ff: int, gated: bool, shared: bool,
+             held: int = 0, shared_d_ff: int = 0) -> dict:
+    """Router over all ``num_experts``; weights of ``held`` of them (0: all);
+    a shared expert of width ``shared_d_ff`` (0: ``d_ff``)."""
+    held = held or num_experts
     spec = {
         "router": P((d_model, num_experts), ("embed", "experts"), init="small"),
-        "w_up": P((num_experts, d_model, d_ff), ("experts", "embed", "ffn")),
-        "w_down": P((num_experts, d_ff, d_model), ("experts", "ffn", "embed")),
+        "w_up": P((held, d_model, d_ff), ("experts", "embed", "ffn")),
+        "w_down": P((held, d_ff, d_model), ("experts", "ffn", "embed")),
     }
     if gated:
-        spec["w_gate"] = P((num_experts, d_model, d_ff), ("experts", "embed", "ffn"))
+        spec["w_gate"] = P((held, d_model, d_ff), ("experts", "embed", "ffn"))
     if shared:
         from repro.models.layers import mlp_spec
 
-        spec["shared"] = mlp_spec(d_model, d_ff, gated)
+        spec["shared"] = mlp_spec(d_model, shared_d_ff or d_ff, gated)
     return spec
+
+
+def held_forward(params, x, cfg):
+    """The held experts' share of a top-k-softmax MoE layer.
+
+    x: (B, S, D) -> (y, counts): ``y`` is the gated sum of the held
+    experts' outputs over the tokens routed to them, plus the shared expert
+    (every chip of the deployment computes it alike, and it counts once);
+    ``counts`` int32 (2,): (token, held expert) pairs routed, and held
+    experts reached by at least one token.
+
+    Every held expert runs on every token, and the gates (zero where a
+    token is not routed to it) pick the results: at serving shapes (16
+    tokens a decode step, 128 a prefill) the step reads the held experts'
+    weights once either way, and the dense pass has no data-dependent
+    shape.
+    """
+    from repro.models.layers import mlp
+
+    b, s, d = x.shape
+    t = x.reshape(-1, d)
+    logits = (t @ params["router"]).astype(jnp.float32)  # (T, E): all experts
+    top, idx = jax.lax.top_k(logits, cfg.moe_top_k)  # (T, k)
+    gates = jax.nn.softmax(top, axis=-1)
+    held = params["w_up"].shape[0]
+    mine = (idx - cfg.expert_offset)[..., None] == jnp.arange(held)  # (T, k, held)
+    gate = jnp.sum(jnp.where(mine, gates[..., None], 0.0), axis=1)  # (T, held)
+    routed = jnp.any(mine, axis=1)
+    up = jnp.einsum("td,edf->etf", t, params["w_up"])
+    if "w_gate" in params:
+        h = _act(cfg.activation, jnp.einsum("td,edf->etf", t, params["w_gate"])) * up
+    else:
+        h = _act(cfg.activation, up)
+    out = jnp.einsum("etf,efd->etd", h, params["w_down"])
+    y = jnp.einsum("te,etd->td", gate.astype(out.dtype), out)
+    if "shared" in params:
+        y = y + mlp(params["shared"], t, cfg.activation)
+    counts = jnp.stack([jnp.sum(routed), jnp.sum(jnp.any(routed, axis=0))]).astype(jnp.int32)
+    return y.reshape(b, s, d).astype(x.dtype), counts
 
 
 def _capacity(group: int, top_k: int, num_experts: int, factor: float) -> int:

@@ -188,7 +188,7 @@ def ssd_forward(params, x, cfg, conv_state=None, ssm_state_in=None):
         raise NotImplementedError("prefix ssm state continuation not supported")
     y = y + params["D"].astype(x.dtype)[None, None, :, None] * xin
     y = y.reshape(b, s, din)
-    y = _gated_rmsnorm(params["norm_scale"], y, z)
+    y = _gated_rmsnorm(params["norm_scale"], y, z, cfg.norm_eps)
     return y @ params["out_proj"], (conv_state, ssm_state.astype(jnp.float32))
 
 
@@ -223,7 +223,7 @@ def ssd_decode_step(params, x, cache, cfg):
     ssm_state = ssm_state.reshape(b, h, p, n)
     y = y.reshape(b, h, p) + params["D"].astype(jnp.float32)[None, :, None] * xin.astype(jnp.float32)
     y = y.reshape(b, 1, din).astype(x.dtype)
-    y = _gated_rmsnorm(params["norm_scale"], y, z)
+    y = _gated_rmsnorm(params["norm_scale"], y, z, cfg.norm_eps)
     return y @ params["out_proj"], (conv_state, ssm_state)
 
 

@@ -42,11 +42,15 @@ def _tail_kinds(cfg):
 
 def _logits(params, cfg, x):
     table = {"embedding": params["lm_head"] if "lm_head" in params else params["embed"]["embedding"]}
+    if cfg.logits_scaling != 1.0:
+        x = x / cfg.logits_scaling
     return logits_from_embed(table, x, cfg.logit_softcap)
 
 
 def _embed(params, cfg, tokens):
     x = embed_tokens(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     return shard_act(x.astype(dtype), "act_btd")
 
@@ -83,7 +87,7 @@ def hidden_states(params, tokens, cfg):
     for tp, kind in zip(params["tail"], _tail_kinds(cfg)):
         x, a = blocks.block_full(tp, x, cfg, kind)
         aux = aux + a
-    return rmsnorm(params["final_norm"], x), aux
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
 
 
 def chunked_xent(x, table, targets, mask, softcap_value: float, chunk: int):
@@ -128,6 +132,8 @@ def loss_fn(params, batch, cfg):
     Returns (loss, metrics)."""
     tokens = batch["tokens"]
     x, aux = hidden_states(params, tokens[:, :-1], cfg)
+    if cfg.logits_scaling != 1.0:
+        x = x / cfg.logits_scaling
     targets = tokens[:, 1:]
     mask = batch.get("mask")
     mask = jnp.ones_like(targets, jnp.float32) if mask is None else mask[:, 1:].astype(jnp.float32)
@@ -142,71 +148,92 @@ def loss_fn(params, batch, cfg):
 # ------------------------------------------------------------------ prefill
 
 
-def prefill(params, tokens, cfg, max_len: int):
-    """Process a full prompt; returns (last_logits (B, V), cache).
+def _total(counts):
+    """Sum of the layers' counters (None where no layer counts)."""
+    counts = [c for c in counts if c is not None]
+    return functools.reduce(jnp.add, counts) if counts else None
+
+
+def _scanned_total(counts):
+    """``_total`` of counters stacked over the scanned periods."""
+    return None if counts is None else counts.sum(axis=0)
+
+
+def prefill(params, tokens, cfg, max_len: int, counts: bool = False):
+    """Process a full prompt; returns (last_logits (B, V), cache), and with
+    ``counts`` the held-expert MoE layers' counters summed over the layers
+    (int32 (2,): routed pairs, experts reached; None without such layers).
 
     cache = {"blocks": [stacked per pattern position], "tail": [...],
              "pos": scalar int32 (= prompt length)}."""
     x = _embed(params, cfg, tokens)
 
     def period_body(x, period_params):
-        caches = []
+        caches, n = [], []
         for p_idx, kind in enumerate(cfg.pattern):
             x = shard_act(x, "act_btd")
-            x, cache, _ = blocks.block_prefill(period_params[p_idx], x, cfg, kind, max_len)
+            x, cache, c = blocks.block_prefill(period_params[p_idx], x, cfg, kind, max_len)
             caches.append(cache)
-        return x, caches
+            n.append(c)
+        return x, (caches, _total(n))
 
     body = period_body
     if cfg.remat:
         body = jax.checkpoint(period_body, prevent_cse=False)
     if cfg.n_periods > 0:
-        x, block_caches = jax.lax.scan(lambda c, p: body(c, p), x, params["blocks"])
+        x, (block_caches, n) = jax.lax.scan(lambda c, p: body(c, p), x, params["blocks"])
+        n = [_scanned_total(n)]
     else:
-        block_caches = []
+        block_caches, n = [], []
     tail_caches = []
     for tp, kind in zip(params["tail"], _tail_kinds(cfg)):
-        x, cache, _ = blocks.block_prefill(tp, x, cfg, kind, max_len)
+        x, cache, c = blocks.block_prefill(tp, x, cfg, kind, max_len)
         tail_caches.append(cache)
-    x = rmsnorm(params["final_norm"], x)
+        n.append(c)
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = _logits(params, cfg, x[:, -1, :])
     cache = {
         "blocks": block_caches,
         "tail": tail_caches,
         "pos": jnp.asarray(tokens.shape[1], jnp.int32),
     }
-    return logits, cache
+    return (logits, cache, _total(n)) if counts else (logits, cache)
 
 
 # ------------------------------------------------------------------ decode
 
 
-def decode_step(params, cache, tokens, cfg):
+def decode_step(params, cache, tokens, cfg, counts: bool = False):
     """One decode step.  tokens: (B, 1) int32; cache from ``prefill`` (or
-    ``repro.models.kvcache.init_cache``).  Returns (logits (B, V), cache)."""
+    ``repro.models.kvcache.init_cache``).  Returns (logits (B, V), cache),
+    and with ``counts`` the step's counters as ``prefill`` gives them."""
     pos = cache["pos"]
     x = _embed(params, cfg, tokens)
 
     def period_body(x, xs):
         period_params, period_cache = xs
-        new_caches = []
+        new_caches, n = [], []
         for p_idx, kind in enumerate(cfg.pattern):
-            x, c, _ = blocks.block_decode(
+            x, c, k = blocks.block_decode(
                 period_params[p_idx], x, period_cache[p_idx], pos, cfg, kind
             )
             new_caches.append(c)
-        return x, new_caches
+            n.append(k)
+        return x, (new_caches, _total(n))
 
     if cfg.n_periods > 0:
-        x, new_block_caches = jax.lax.scan(
+        x, (new_block_caches, n) = jax.lax.scan(
             period_body, x, (params["blocks"], cache["blocks"])
         )
+        n = [_scanned_total(n)]
     else:
-        new_block_caches = []
+        new_block_caches, n = [], []
     new_tail = []
     for tp, tc, kind in zip(params["tail"], cache["tail"], _tail_kinds(cfg)):
-        x, c, _ = blocks.block_decode(tp, x, tc, pos, cfg, kind)
+        x, c, k = blocks.block_decode(tp, x, tc, pos, cfg, kind)
         new_tail.append(c)
-    x = rmsnorm(params["final_norm"], x)
+        n.append(k)
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = _logits(params, cfg, x[:, -1, :])
-    return logits, {"blocks": new_block_caches, "tail": new_tail, "pos": pos + 1}
+    cache = {"blocks": new_block_caches, "tail": new_tail, "pos": pos + 1}
+    return (logits, cache, _total(n)) if counts else (logits, cache)

@@ -73,6 +73,13 @@ class ExecutionReport:
     tokens: np.ndarray  # (B, new_tokens) generated ids
     predictions: list  # per-request predicted class (argmax over option logits)
     worker: int = -1  # lane that executed the batch (-1: single-executor path)
+    # Held-expert MoE counters of the forward that ran the batch (0 for other
+    # models; of a fused forward, the first of its reports carries them):
+    # (row, held expert) pairs routed over the prefill and every decode step,
+    # padded rows included, and the held experts each decode step reached,
+    # summed over MoE layers and steps.
+    routed_pairs: int = 0
+    experts_reached: int = 0
 
     @property
     def total_s(self) -> float:
@@ -127,6 +134,10 @@ class ExecutorBackend:
     # Blocking device-to-host waits (two per forward); only the backends
     # that run real forwards count them.
     host_syncs: int = 0
+    # Held-expert MoE counters summed over every forward (``ExecutionReport``
+    # has one forward's); only the backends that run real forwards count.
+    routed_pairs: int = 0
+    experts_reached: int = 0
 
     def __init__(self, variants: Mapping[str, tuple], new_tokens: int = 4):
         self.variants = dict(variants)
@@ -208,21 +219,25 @@ def _programs(model: LM, new_tokens: int, donate_cache: bool):
     them back once.  Named functions, so their modules read
     ``jit_prefill`` and ``jit_decode_step`` in a profiler trace.
 
-    ``prefill(params, tokens, class_ids=None) -> (tok, cache, preds)``:
+    ``prefill(params, tokens, class_ids=None) -> (tok, cache, preds, n)``:
     ``tok`` is the (B,) int32 pick from the last position's logits and
     ``preds`` the pick among the logits of ``class_ids`` (None without
-    them).  ``decode_step(params, cache, tok) -> (tok, cache)`` feeds the
-    (B,) tokens back as one position each.
+    them).  ``decode_step(params, cache, tok) -> (tok, cache, n)`` feeds the
+    (B,) tokens back as one position each.  ``n`` is the held-expert MoE
+    counters of the program (int32 (2,): routed pairs, experts reached), or
+    None for a model without such layers.
     """
+    kw = {"counts": True} if model.cfg.counts_experts else {}
 
     def prefill(params, tokens, class_ids=None):
-        logits, cache = model.prefill(params, tokens, max_len=tokens.shape[1] + new_tokens)
+        logits, cache, *n = model.prefill(params, tokens, max_len=tokens.shape[1] + new_tokens,
+                                          **kw)
         preds = None if class_ids is None else jnp.argmax(logits[:, class_ids], axis=-1)
-        return _greedy(logits), cache, preds
+        return _greedy(logits), cache, preds, (n[0] if n else None)
 
     def decode_step(params, cache, tok):
-        logits, cache = model.decode_step(params, cache, tok[:, None])
-        return _greedy(logits), cache
+        logits, cache, *n = model.decode_step(params, cache, tok[:, None], **kw)
+        return _greedy(logits), cache, (n[0] if n else None)
 
     return jax.jit(prefill), jax.jit(decode_step, donate_argnums=(1,) if donate_cache else ())
 
@@ -242,6 +257,8 @@ class _ProgramBackend(ExecutorBackend):
         self._prefill_jit: dict[str, Callable] = {}
         self._decode_jit: dict[str, Callable] = {}
         self.host_syncs = 0
+        self.routed_pairs = 0
+        self.experts_reached = 0
 
     def _get(self, name: str):
         if name not in self._models:
@@ -258,38 +275,51 @@ class _ProgramBackend(ExecutorBackend):
         """Greedy decoding of ``new_tokens`` tokens for (B, S) ``prompts``;
         returns (prefill_s, decode_s, tokens (B, new_tokens), preds or
         None).  The host waits twice: for the prefill's token, which ends
-        ``prefill_s``, and for one readback of every token (and ``preds``)
-        once the last is ready, which ends ``decode_s``."""
+        ``prefill_s``, and for one readback of every token (and ``preds``,
+        and the held-expert counters, which it adds to the backend's) once
+        the last is ready, which ends ``decode_s``."""
         _, params = self._get(model_name)
         class_ids = (None if class_token_ids is None
                      else jnp.asarray(class_token_ids, jnp.int32))
         t0 = time.perf_counter()
         with tracing.span("exec.prefill"):
-            tok, cache, preds = self._prefill_jit[model_name](
+            tok, cache, preds, n = self._prefill_jit[model_name](
                 params, jnp.asarray(prompts), class_ids)
             tok.block_until_ready()
             self.host_syncs += 1
         t1 = time.perf_counter()
         with tracing.span("exec.decode"):
-            toks = [tok]
+            toks, counts = [tok], [n]
             for _ in range(self.new_tokens - 1):
-                tok, cache = self._decode_jit[model_name](params, cache, tok)
+                tok, cache, n = self._decode_jit[model_name](params, cache, tok)
                 toks.append(tok)
-            toks, preds = jax.device_get((toks, preds))
+                counts.append(n)
+            toks, preds, counts = jax.device_get((toks, preds, counts))
             self.host_syncs += 1
         t2 = time.perf_counter()
+        if counts[0] is not None:
+            self.routed_pairs += int(sum(int(c[0]) for c in counts))
+            self.experts_reached += int(sum(int(c[1]) for c in counts[1:]))
         return t1 - t0, t2 - t1, np.stack(toks, axis=1), preds
 
     @contextlib.contextmanager
     def _forward_span(self, model_name: str, rows: int, padded: int, rids):
         """The ``exec.forward`` span, given the host waits of its forward
-        as ``syncs`` once they are known."""
+        as ``syncs`` once they are known, and for a held-expert MoE model
+        its ``routed_pairs`` and ``experts_reached``.  Yields a dict that
+        holds those two counters of the forward at the end."""
         sp = tracing.span("exec.forward", model=model_name, rows=rows, padded=padded,
                           rids=rids)
-        before = self.host_syncs
+        syncs, pairs, reached = self.host_syncs, self.routed_pairs, self.experts_reached
+        counts = {}
         with sp:
-            yield
-            sp.set_metadata(syncs=self.host_syncs - before)
+            yield counts
+            counts.update(routed_pairs=self.routed_pairs - pairs,
+                          experts_reached=self.experts_reached - reached)
+            meta = {"syncs": self.host_syncs - syncs}
+            if self.variants[model_name][0].counts_experts:
+                meta.update(counts)
+            sp.set_metadata(**meta)
 
 
 class ProfiledBackend(_ProgramBackend):
@@ -307,7 +337,7 @@ class ProfiledBackend(_ProgramBackend):
                   class_token_ids: Optional[np.ndarray] = None) -> ExecutionReport:
         """prompts: (B, S) int32 (pre-padded)."""
         b = prompts.shape[0]
-        with self._forward_span(model_name, b, b, request_ids):
+        with self._forward_span(model_name, b, b, request_ids) as counts:
             prefill_s, decode_s, tokens, preds = self._generate(
                 model_name, prompts, class_token_ids)
             self._record(model_name, b, prefill_s + decode_s)
@@ -320,6 +350,7 @@ class ProfiledBackend(_ProgramBackend):
             decode_s=decode_s,
             tokens=tokens,
             predictions=list(preds) if preds is not None else [None] * b,
+            **counts,
         )
 
 
@@ -417,7 +448,7 @@ class CompiledBackend(_ProgramBackend):
         carries the UNPADDED rows (timing covers the padded shape)."""
         b = prompts.shape[0]
         padded = self._pad(prompts)
-        with self._forward_span(model_name, b, padded.shape[0], request_ids):
+        with self._forward_span(model_name, b, padded.shape[0], request_ids) as counts:
             prefill_s, decode_s, tokens, preds = self._forward(
                 model_name, padded, class_token_ids)
         return ExecutionReport(
@@ -425,6 +456,7 @@ class CompiledBackend(_ProgramBackend):
             swap_s=0.0, prefill_s=prefill_s, decode_s=decode_s,
             tokens=tokens[:b],
             predictions=list(preds[:b]) if preds is not None else [None] * b,
+            **counts,
         )
 
     def run_batches(self, model_name: str, prompt_list: Sequence[np.ndarray],
@@ -443,7 +475,7 @@ class CompiledBackend(_ProgramBackend):
             merged[row:row + p.shape[0], :p.shape[1]] = p
             row += p.shape[0]
         padded = self._pad(merged)
-        with self._forward_span(model_name, total, padded.shape[0], rid_lists):
+        with self._forward_span(model_name, total, padded.shape[0], rid_lists) as counts:
             prefill_s, decode_s, tokens, preds = self._forward(
                 model_name, padded, class_token_ids)
         reports = []
@@ -456,6 +488,7 @@ class CompiledBackend(_ProgramBackend):
                 tokens=tokens[row:row + b],
                 predictions=(list(preds[row:row + b]) if preds is not None
                              else [None] * b),
+                **(counts if row == 0 else {}),
             ))
             row += b
         return reports

@@ -212,7 +212,7 @@ def _prompts(rows, seq=8, vocab=128, seed=0):
 
 
 @pytest.mark.parametrize("new_tokens", [2, 4])
-@pytest.mark.parametrize("arch", ["granite-8b", "mamba2-130m"])
+@pytest.mark.parametrize("arch", ["granite-8b", "mamba2-130m", "granite-4.0-h-small"])
 def test_compiled_backend_tokens_equal_greedy_generate(arch, new_tokens):
     """Tokens picked inside the jitted programs are LM.generate's greedy
     tokens; 3 rows run padded to 4."""
@@ -461,3 +461,48 @@ def test_edge_server_drift_stats_report_provenance():
     assert stats.profile_provenance == {"m": "costmodel"}
     assert set(stats.realized_over_profiled) <= {0, 1}
     assert stats.realized_over_profiled  # drift observed on served lanes
+
+
+def _held_moe():
+    """granite-4.0-h-small at a small size, 2 of its 8 experts held."""
+    import dataclasses
+
+    return dataclasses.replace(_reduced("granite-4.0-h-small"), experts_here=2, expert_offset=2)
+
+
+@pytest.mark.parametrize("backend", [CompiledBackend, ProfiledBackend])
+def test_held_expert_counters_ride_the_one_readback(backend):
+    """A hybrid SSM/attention model with held-expert MoE layers: still two
+    host waits a forward, and each report carries the forward's counters,
+    which are the model's own (prefill plus every decode step)."""
+    import jax.numpy as jnp
+
+    from repro.models import LM
+
+    cfg = _held_moe()
+    be = backend({"m": (cfg, 0)}, new_tokens=3)
+    prompts = _prompts(4, vocab=cfg.vocab_size, seed=3)
+    rep = be.run_batch("m", prompts, [0, 1, 2, 3])
+    assert be.host_syncs == 2
+    lm = LM(cfg)
+    params = be._get("m")[1]
+    _, cache, n = lm.prefill(params, jnp.asarray(prompts), max_len=11, counts=True)
+    pairs, reached = int(n[0]), 0
+    for j in range(2):
+        _, cache, n = lm.decode_step(params, cache, jnp.asarray(rep.tokens[:, j:j + 1]),
+                                     counts=True)
+        pairs, reached = pairs + int(n[0]), reached + int(n[1])
+    assert (rep.routed_pairs, rep.experts_reached) == (pairs, reached)
+    assert 0 < reached <= 2 * 2 * cfg.num_layers
+    assert (be.routed_pairs, be.experts_reached) == (pairs, reached)
+    if backend is CompiledBackend:
+        reps = be.run_batches("m", [prompts[:3], prompts[3:]], [[0, 1, 2], [3]])
+        assert be.host_syncs == 4
+        assert reps[0].routed_pairs > 0 and reps[1].routed_pairs == 0
+        assert be.routed_pairs == pairs + reps[0].routed_pairs
+
+
+def test_models_without_held_experts_report_no_counters():
+    be = CompiledBackend({"m": (_reduced("mamba2-130m"), 0)}, new_tokens=3)
+    rep = be.run_batch("m", np.ones((2, 8), np.int32), [0, 1])
+    assert (rep.routed_pairs, rep.experts_reached, be.routed_pairs) == (0, 0, 0)

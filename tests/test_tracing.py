@@ -229,3 +229,25 @@ def test_serve_stats_copy_cold_forwards(model_cfg):
     pool.lanes[1].executor.backend.cold_forwards = 3
     assert pool.cold_forwards == 5
     assert not hasattr(stats, "wall_s")
+
+
+def test_forward_span_carries_the_expert_counters(tmp_path):
+    """A held-expert MoE model's ``exec.forward`` gives its routed pairs and
+    experts reached beside its two host waits; other models' spans do not."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("granite-4.0-h-small").reduced(), experts_here=2)
+    be = CompiledBackend({"moe": (cfg, 0), "ssm": (get_config("mamba2-130m").reduced(), 0)},
+                         new_tokens=3)
+    p = np.zeros((3, 8), np.int32)
+
+    def body():
+        return [be.run_batch(m, p, [0, 1, 2]) for m in ("moe", "ssm")]
+
+    reps, spans = _traced(tmp_path, body)
+    fwds = {s[3]["model"]: s[3] for s in spans if s[0] == "exec.forward"}
+    assert fwds["moe"]["syncs"] == 2 and fwds["ssm"]["syncs"] == 2
+    assert (fwds["moe"]["routed_pairs"], fwds["moe"]["experts_reached"]) == (
+        reps[0].routed_pairs, reps[0].experts_reached)
+    assert reps[0].experts_reached > 0
+    assert "routed_pairs" not in fwds["ssm"] and "experts_reached" not in fwds["ssm"]
